@@ -204,6 +204,7 @@ def _scaling_worker(dataset: str, storage: str) -> dict:
     first, last = rows[0], rows[-1]
     return dict(
         local_qps=round(local_qps, 1),
+        device=f"{jax.devices()[0].platform} (fake devices)",
         n_devices=len(jax.devices()),
         note=("single-core host: fake XLA devices execute serially, so qps "
               "is wall-clock with C shards back-to-back and qps_scaled "
@@ -216,12 +217,14 @@ def _scaling_worker(dataset: str, storage: str) -> dict:
 
 
 def _scaling_table(dataset: str, storage: str) -> dict:
-    """Run ``_scaling_worker`` in a subprocess with 8 fake XLA devices (the
-    device count is fixed at backend init, so the parent can't just flip it)."""
+    """Run ``_scaling_worker`` in a subprocess with 8 fake XLA CPU devices
+    (the device count is fixed at backend init, so the parent can't just
+    flip it).  The child is pinned to the CPU: the parent already holds any
+    accelerator, and the table is a fake-device CPU table by construction."""
     import subprocess
 
     root = Path(__file__).parent.parent
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.pathsep.join([str(root), str(root / "src")]))
     proc = subprocess.run(
@@ -231,9 +234,9 @@ def _scaling_table(dataset: str, storage: str) -> dict:
     for line in proc.stdout.splitlines():
         if line.startswith(_SCALING_TAG):
             return json.loads(line[len(_SCALING_TAG):])
-    return dict(error="scaling worker produced no table",
-                returncode=proc.returncode,
-                stderr=proc.stderr.strip().splitlines()[-5:])
+    raise RuntimeError(
+        f"scaling worker produced no table (rc={proc.returncode}):\n"
+        + "\n".join(proc.stderr.strip().splitlines()[-5:]))
 
 
 def _ndpsim_row(idx, db, params: SearchParams, q) -> dict:

@@ -8,12 +8,12 @@ roofline per (arch x shape x mesh) — EXPERIMENTS.md §Roofline.
 from __future__ import annotations
 
 import json
-from pathlib import Path
 
 from repro import configs as C
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.utils import CACHE_DIR
 
-DRYRUN_DIR = Path("/root/repo/.cache/dryrun")
+DRYRUN_DIR = CACHE_DIR / "dryrun"
 
 
 def model_flops(arch: str, shape_name: str) -> float:

@@ -19,7 +19,8 @@ def main():
     db = make_dataset("unit")
     idx = Index.build(db, IndexSpec.for_db(db, m=8, dfloat_recall_target=None))
     n_shards = 4
-    mesh = jax.make_mesh((2, n_shards), ("data", "model"))
+    mesh = jax.make_mesh((2, n_shards), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     print(f"mesh: {mesh.devices.shape} (data x model); DB {db.n}x{db.dim}")
 
     owner = gmod.map_owners(db.n, n_shards, "shuffle")

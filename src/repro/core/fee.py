@@ -113,4 +113,7 @@ def fee_distance(q, x, threshold, alpha, beta, margin, *, seg: int, metric: str 
 def exact_distance(q, x, *, metric: str = "l2"):
     if metric == "l2":
         return ((x - q[None, :]) ** 2).sum(-1)
-    return -(x @ q)
+    # elementwise, not ``x @ q``: a TPU matmul at default precision rounds
+    # its inputs to bf16, and these scores must agree with the FEE kernels'
+    # f32 elementwise sums
+    return -(x * q[None, :]).sum(-1)

@@ -124,7 +124,7 @@ def build_graph(vectors: np.ndarray, m: int = 16, metric: str = "l2",
         return out
 
     if cache_key is not None:
-        data = cached_npz(f"graph/{cache_key}/m{m}/{metric}/p{prune}/l{n_long}/v4", _build)
+        data = cached_npz(f"graph/{cache_key}/m{m}/{metric}/p{prune}/l{n_long}/v5", _build)
     else:
         data = _build()
     levels = []

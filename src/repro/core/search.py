@@ -422,7 +422,8 @@ def _search_batch(vectors, adj, fee, tombstone, queries, entries, *,
 def make_searcher(vectors, adj, cfg: SearchConfig,
                   fee: FeeParams | dict | None = None, trace: bool = False, *,
                   dfloat_cfg: dfl.DfloatConfig | None = None, tombstone=None):
-    """Returns search(queries (Q,D), entries (Q,)) -> dict of results.
+    """Returns search(queries (Q,D), entries (Q,)) -> dict of results;
+    ``search.lower(queries, entries)`` lowers the program it runs.
 
     vectors/adj may be numpy; they are passed to one shared top-level jitted
     program (cached by shape), not closed over as constants.  With
@@ -464,6 +465,14 @@ def make_searcher(vectors, adj, cfg: SearchConfig,
                              jnp.asarray(entries), cfg=cfg, trace=trace,
                              dfl_cfg=dfl_cfg)
 
+    def lower(queries, entries):
+        """The jitted program ``search`` runs, lowered for these query and
+        entry shapes (arrays or ``jax.ShapeDtypeStruct``)."""
+        return _search_batch.lower(vectors, adj, fp, tombstone, queries,
+                                   entries, cfg=cfg, trace=trace,
+                                   dfl_cfg=dfl_cfg)
+
+    search.lower = lower
     return search
 
 

@@ -15,6 +15,7 @@ generator settings.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 
@@ -69,7 +70,9 @@ DATASETS = {
 
 
 def _generate(spec: DatasetSpec, seed: int = 0) -> dict:
-    rng = np.random.default_rng(seed + hash(spec.name) % (2**31))
+    # crc32, not hash(): str hashes are salted per process, and the corpus
+    # must be the same on every machine and run for a given seed
+    rng = np.random.default_rng(seed + zlib.crc32(spec.name.encode()) % (2**31))
     d, n = spec.dim, spec.n
     lam = np.arange(1, d + 1, dtype=np.float64) ** (-spec.spectrum_decay)
     lam /= lam.sum()
@@ -118,7 +121,7 @@ def exact_topk(db: np.ndarray, queries: np.ndarray, k: int, metric: str,
 
 def make_dataset(name: str, seed: int = 0) -> VecDB:
     spec = DATASETS[name]
-    data = cached_npz(f"dataset/{name}/v3/{seed}/{spec}", lambda: _generate(spec, seed))
+    data = cached_npz(f"dataset/{name}/v4/{seed}/{spec}", lambda: _generate(spec, seed))
     nq = spec.n_queries
     return VecDB(
         name=name,

@@ -55,7 +55,6 @@ from repro.core import fee as fee_mod
 from repro.core import search as search_mod
 from repro.core.fee import FeeParams
 from repro.core.search import SearchConfig, first_occurrence_mask
-from repro.distributed import compat
 from repro.kernels import ops as kops
 
 BIG = jnp.float32(3.0e38)
@@ -102,7 +101,9 @@ def build_sharded_db(vectors: np.ndarray, dam, dtype=None,
     (row layout is identical either way), or a (coarse, residual) tier pair —
     each tier is then sharded with the same row map, keeping residual fetches
     shard-local.  By default integer inputs keep their dtype and float inputs
-    are cast to f32 (the pre-packed guarantee).
+    are cast to f32 (the pre-packed guarantee).  The arrays stay on the host:
+    ``jax.device_put`` with :func:`db_shardings` ships each shard straight to
+    its own device, never staging the whole stack on the first one.
 
     ``tombstone`` is the *global* packed dead-row bitmap of an Index
     snapshot; it is re-folded here into per-shard words indexed by local
@@ -141,8 +142,7 @@ def build_sharded_db(vectors: np.ndarray, dam, dtype=None,
             idx = slot[dead]
             np.bitwise_or.at(tomb[ch], idx >> 5,
                              np.uint32(1) << (idx & 31).astype(np.uint32))
-        tomb = jnp.asarray(tomb)
-    return ShardedDB(jnp.asarray(vs), jnp.asarray(ids), jnp.asarray(pa), tomb)
+    return ShardedDB(vs, ids, pa, tomb)
 
 
 def db_shardings(mesh: Mesh):
@@ -433,12 +433,12 @@ def make_sharded_searcher(mesh: Mesh, cfg: SearchConfig, n_total: int,
         # as a static empty pytree
         wrapped = body
         body_in = lambda v, i, p, q, en: wrapped(v, i, p, None, q, en)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             body_in, mesh=mesh,
             in_specs=tuple(in_specs[:3] + in_specs[4:]),
             out_specs=(out_p, out_p), check_vma=False)
     else:
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh, in_specs=tuple(in_specs),
             out_specs=(out_p, out_p), check_vma=False)
 

@@ -156,6 +156,7 @@ def local_searcher(index, params: SearchParams, *, fee=None):
         _record_search(res, index.dim, bpd)
         return res
 
+    run.lower = searcher.lower   # (rotated queries, entries) -> Lowered
     return run
 
 
@@ -172,11 +173,12 @@ def sharded_searcher(index, params: SearchParams, *, mesh=None,
     stable capacity-wide map so appends never reshuffle resident rows);
     ``overlap=True`` selects the double-buffered stale-threshold pipeline.
     The returned ``run`` exposes the per-hop collective payload model as
-    ``run.payload`` (see ``distributed.retrieval.collective_payload``)."""
+    ``run.payload`` (see ``distributed.retrieval.collective_payload``) and
+    the device-placed :class:`~repro.distributed.retrieval.ShardedDB` as
+    ``run.db``."""
     import jax
     import jax.numpy as jnp
 
-    from repro.distributed import compat
     from repro.distributed import retrieval as rt
 
     if params.trace:
@@ -189,7 +191,8 @@ def sharded_searcher(index, params: SearchParams, *, mesh=None,
             raise ValueError(f"n_shards={n_shards} must divide the available "
                              f"device count ({ndev}); pass an explicit mesh "
                              "to use a device subset")
-        mesh = jax.make_mesh((ndev // n_shards, n_shards), ("data", "model"))
+        mesh = jax.make_mesh((ndev // n_shards, n_shards), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
     else:
         model_axis = "model" if "model" in mesh.axis_names else mesh.axis_names[-1]
         n_shards = mesh.shape[model_axis]
@@ -200,7 +203,7 @@ def sharded_searcher(index, params: SearchParams, *, mesh=None,
     dam = gmod.build_dam(index.graph.base_adjacency, owner, n_shards)
     cfg = params.to_config(index.metric, index.seg)
     tomb = index.tombstone
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         searcher = rt.make_sharded_searcher(mesh, cfg, index.n,
                                             fee=_fee(index, params, fee),
                                             n_bits_log2=n_bits_log2,
@@ -219,13 +222,14 @@ def sharded_searcher(index, params: SearchParams, *, mesh=None,
     def run(queries) -> SearchResult:
         qr = index.transform_queries(np.asarray(queries))
         entries = search_mod.descend_entry(rows, index.graph, qr, index.metric)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             ids, dists = searcher(sdb, jnp.asarray(qr), jnp.asarray(entries))
         return SearchResult(ids=np.asarray(ids), dists=np.asarray(dists),
                             generation=index.generation)
 
     run.payload = rt.collective_payload(cfg, max(p.shape[1] for p in dam.part_adj),
                                         n_shards)
+    run.db = sdb
     return run
 
 

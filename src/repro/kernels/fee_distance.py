@@ -11,6 +11,12 @@ is compared against the beam threshold; lanes that exit stop accumulating,
 and once an entire candidate tile has exited the remaining feature blocks'
 *compute* is skipped (`pl.when`).
 
+The kernels are feature-major: candidates fill the 128 lanes and features
+(or packed words) run down the sublanes, so a ``seg``-feature block is a
+sublane slice of whole (8, 128) tiles, the per-lane accumulators are one
+lane-dense row, and no layout assumes ``D % 128 == 0``.  The wrappers take
+row-major (C, D) / (C, W) candidates and transpose them on the way in.
+
 Three variants share the accumulate/exit logic:
 
   * ``fee_distance_pallas``        — f32 features, automatic block pipelining
@@ -24,10 +30,12 @@ Three variants share the accumulate/exit logic:
     VPE datapath (Fig. 10d->10c): candidates arrive as the packed uint32
     bitstream and are decoded in VMEM with static barrel-shifter offsets, so
     only packed bytes ever cross HBM.  ``skip_dma=True`` additionally keeps
-    the bitstream in HBM and manually DMAs only the burst-aligned word range
-    of each live feature block.
+    the bitstream in HBM and manually DMAs only the word range of each live
+    feature block.
 
-Grid: (C // TILE_C, S) with the segment axis sequential ("arbitrary") so the
+Grid: (Q, C // TILE_C, S).  The leading query axis is what ``jax.vmap`` over
+the single-query wrappers turns into (the search loop vmaps them over its
+query batch); the segment axis is sequential ("arbitrary") so the
 accumulator scratch persists across feature blocks of one candidate tile.
 """
 from __future__ import annotations
@@ -40,16 +48,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import dfloat as dfl
+from repro.kernels.dfloat_unpack import decode_rows
 
 BIG = 3.0e38
+SUBLANES = 8              # rows of one (8, 128) 32-bit VMEM tile
 
 
-def _compiler_params_cls():
-    for name in ("CompilerParams", "TPUCompilerParams"):  # new / 0.4.x name
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise RuntimeError("unsupported jax/pallas version: no TPU CompilerParams")
+# ---------------------------------------------------------------------------
+# shared pieces
+# ---------------------------------------------------------------------------
 
 
 def _init_scratch(s, acc, alive, nseg):
@@ -61,20 +68,24 @@ def _init_scratch(s, acc, alive, nseg):
 
 
 def _part_distance(x, q, metric: str):
+    """x (seg, TILE_C) features, q (seg, 1) -> (1, TILE_C) partial score."""
     if metric == "l2":
-        return ((x - q) ** 2).sum(axis=1, keepdims=True)       # (TILE_C, 1)
-    return -(x * q).sum(axis=1, keepdims=True)
+        return ((x - q) ** 2).sum(axis=0, keepdims=True)
+    return -(x * q).sum(axis=0, keepdims=True)
 
 
-def _accumulate_exit(part, s, thr_ref, alpha_ref, beta_ref, margin_ref,
-                     acc, alive, nseg, last_valid_seg: int):
+def _accumulate_exit(x, k, q_ref, thr, alpha_ref, beta_ref, margin_ref,
+                     acc, alive, nseg, *, metric: str, last_valid_seg: int):
+    """Score feature block ``k`` (x (seg, TILE_C)) into the live lanes;
+    ``thr`` is this query's beam threshold."""
+    part = _part_distance(x, q_ref[:, :], metric)
     live = alive[:] > 0
     acc[:] = acc[:] + jnp.where(live, part, 0.0)
     nseg[:] = nseg[:] + jnp.where(live, 1, 0)
-    est = alpha_ref[s] * acc[:] / beta_ref[s] - margin_ref[s]
+    est = alpha_ref[k] * acc[:] / beta_ref[k] - margin_ref[k]
     # exits only before the last segment (paper Fig. 6: at the last access
     # the full distance is available anyway)
-    exit_now = live & (est >= thr_ref[0]) & (s < last_valid_seg)
+    exit_now = live & (est >= thr) & (k < last_valid_seg)
     alive[:] = jnp.where(exit_now, 0, alive[:])
 
 
@@ -87,19 +98,162 @@ def _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg,
         segs_ref[:, :] = nseg[:]
 
 
+def _word_span(w0: int, w1: int, n_words: int) -> tuple[int, int]:
+    """Widen a word range ``[w0, w1)`` to whole sublane tiles (capped at the
+    row's ``n_words``) so its DMA moves whole (8, 128) tiles."""
+    a = w0 - w0 % SUBLANES
+    return a, min(-(-w1 // SUBLANES) * SUBLANES, n_words)
+
+
+def _feature_major(x, tile_c: int):
+    """(Q, C, F) candidate rows -> (Q, F, Cp): candidates on the lanes,
+    padded to whole tiles."""
+    pad = (-x.shape[1]) % tile_c
+    return jnp.pad(jnp.swapaxes(x, 1, 2), ((0, 0), (0, 0), (0, pad)))
+
+
+def _fee_call(kern, q, xs, x_specs, thr, alpha, beta, margin, *, seg: int,
+              tile_c: int, scratch, interpret: bool):
+    """Launch one FEE kernel over a query batch.
+
+    ``q`` (Q, D); ``xs`` the feature-major candidate operands (Q, F, Cp)
+    with their ``x_specs``; ``thr`` (Q,).  Returns (dist, rejected,
+    segs_used), each (Q, Cp).
+    """
+    nq, d = q.shape
+    n_segs = d // seg
+    assert n_segs * seg == d, (d, seg)
+    cp = xs[0].shape[-1]
+    lane_row = pl.BlockSpec((None, 1, tile_c), lambda b, i, s: (b, 0, i))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    dist, rej, segs = pl.pallas_call(
+        functools.partial(kern, n_segs=n_segs, last_valid_seg=n_segs - 1),
+        grid=(nq, cp // tile_c, n_segs),
+        in_specs=[
+            pl.BlockSpec((None, seg, 1), lambda b, i, s: (b, s, 0)),  # q col
+            *x_specs,
+            smem, smem, smem, smem,             # threshold, alpha, beta, margin
+        ],
+        out_specs=[lane_row, lane_row, lane_row],
+        out_shape=[
+            jax.ShapeDtypeStruct((nq, 1, cp), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1, cp), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, cp), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((1, tile_c), jnp.float32),   # acc
+            pltpu.VMEM((1, tile_c), jnp.int32),     # alive
+            pltpu.VMEM((1, tile_c), jnp.int32),     # nseg
+            *scratch,
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(q[:, :, None], *xs, thr.astype(jnp.float32), alpha.astype(jnp.float32),
+      beta.astype(jnp.float32), margin.astype(jnp.float32))
+    return dist[:, 0], rej[:, 0] > 0, segs[:, 0]
+
+
+def _one_query(batched_fn, n_batched: int):
+    """Single-query entry point for ``batched_fn``, whose first ``n_batched``
+    operands carry a leading query axis and whose outputs are (Q, Cp).
+
+    A ``jax.vmap`` over the returned function folds the mapped axis into that
+    query axis — the kernel's outermost grid dimension — rather than letting
+    the generic ``pallas_call`` batching rule add a grid axis the kernel
+    cannot see (a manual DMA from a ``pl.ANY`` ref has to index the query
+    itself).  The FEE parameters must not be mapped.
+    """
+    @jax.custom_batching.custom_vmap
+    def call(*args):
+        return batched_fn(*args)
+
+    @call.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        if any(in_batched[n_batched:]):
+            raise NotImplementedError("FEE parameters cannot be vmapped")
+        lead = [a if mapped else jnp.broadcast_to(a, (axis_size, *a.shape))
+                for a, mapped in zip(args[:n_batched], in_batched)]
+        nq = lead[0].shape[1]
+        outs = call(*(a.reshape(axis_size * nq, *a.shape[2:]) for a in lead),
+                    *args[n_batched:])
+        return (tuple(o.reshape(axis_size, nq, *o.shape[1:]) for o in outs),
+                (True,) * len(outs))
+
+    def one(*args):
+        outs = call(*(a[None] for a in args[:n_batched]), *args[n_batched:])
+        return tuple(o[0] for o in outs)
+
+    return one
+
+
+# ---------------------------------------------------------------------------
+# f32 candidates
+# ---------------------------------------------------------------------------
+
+
 def _kernel(q_ref, x_ref, thr_ref, alpha_ref, beta_ref, margin_ref,
-            dist_ref, rej_ref, segs_ref,
-            acc, alive, nseg, *, metric: str, n_segs: int, last_valid_seg: int):
-    s = pl.program_id(1)
+            dist_ref, rej_ref, segs_ref, acc, alive, nseg,
+            *, metric: str, n_segs: int, last_valid_seg: int):
+    b, s = pl.program_id(0), pl.program_id(2)
+    thr = thr_ref[b]
     _init_scratch(s, acc, alive, nseg)
 
     @pl.when(alive[:].max() > 0)
     def _compute():
-        part = _part_distance(x_ref[:, :], q_ref[:, :], metric)
-        _accumulate_exit(part, s, thr_ref, alpha_ref, beta_ref, margin_ref,
-                         acc, alive, nseg, last_valid_seg)
+        _accumulate_exit(x_ref[:, :], s, q_ref, thr, alpha_ref, beta_ref,
+                         margin_ref, acc, alive, nseg, metric=metric,
+                         last_valid_seg=last_valid_seg)
 
     _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+
+
+def _skipdma_kernel(q_ref, x_hbm, thr_ref, alpha_ref, beta_ref, margin_ref,
+                    dist_ref, rej_ref, segs_ref, acc, alive, nseg, buf, sem,
+                    *, metric: str, n_segs: int, last_valid_seg: int,
+                    seg: int, tile_c: int):
+    b, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    thr = thr_ref[b]
+    _init_scratch(s, acc, alive, nseg)
+
+    @pl.when(alive[:].max() > 0)
+    def _fetch_compute():
+        # the burst stream for this feature block is issued only while the
+        # tile is live — this is the skip_dma contract
+        dma = pltpu.make_async_copy(
+            x_hbm.at[b, pl.ds(pl.multiple_of(s * seg, seg), seg),
+                     pl.ds(pl.multiple_of(i * tile_c, tile_c), tile_c)],
+            buf, sem)
+        dma.start()
+        dma.wait()
+        _accumulate_exit(buf[:, :], s, q_ref, thr, alpha_ref, beta_ref,
+                         margin_ref, acc, alive, nseg, metric=metric,
+                         last_valid_seg=last_valid_seg)
+
+    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+
+
+@functools.lru_cache(maxsize=None)
+def _f32_fn(seg: int, metric: str, tile_c: int, interpret: bool,
+            skip_dma: bool):
+    def batched(q, x, thr, alpha, beta, margin):
+        xt = _feature_major(x, tile_c)                      # (Q, D, Cp)
+        if skip_dma:
+            kern = functools.partial(_skipdma_kernel, metric=metric, seg=seg,
+                                     tile_c=tile_c)
+            x_spec = pl.BlockSpec(memory_space=pl.ANY)
+            scratch = [pltpu.VMEM((seg, tile_c), jnp.float32),  # landing buf
+                       pltpu.SemaphoreType.DMA]
+        else:
+            kern = functools.partial(_kernel, metric=metric)
+            x_spec = pl.BlockSpec((None, seg, tile_c),
+                                  lambda b, i, s: (b, s, i))
+            scratch = []
+        return _fee_call(kern, q, [xt], [x_spec], thr, alpha, beta, margin,
+                         seg=seg, tile_c=tile_c, scratch=scratch,
+                         interpret=interpret)
+    return _one_query(batched, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("seg", "metric", "tile_c", "interpret"))
@@ -112,80 +266,9 @@ def fee_distance_pallas(q, x, threshold, alpha, beta, margin, *,
     accumulated score for rejected lanes (unused by the search, matching the
     hardware which stops the burst stream on exit).
     """
-    c, d = x.shape
-    n_segs = d // seg
-    assert n_segs * seg == d, (d, seg)
-    pad_c = (-c) % tile_c
-    if pad_c:
-        x = jnp.pad(x, ((0, pad_c), (0, 0)))
-    cp = c + pad_c
-    q2 = q.reshape(1, d)
-    thr = jnp.reshape(threshold, (1,)).astype(jnp.float32)
-
-    grid = (cp // tile_c, n_segs)
-    kern = functools.partial(_kernel, metric=metric, n_segs=n_segs,
-                             last_valid_seg=n_segs - 1)
-    dist, rej, segs = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, seg), lambda i, s: (0, s)),            # q
-            pl.BlockSpec((tile_c, seg), lambda i, s: (i, s)),       # x
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # threshold
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # beta
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # margin
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile_c, 1), jnp.float32),   # acc
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # alive
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # nseg
-        ],
-        compiler_params=_compiler_params_cls()(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q2, x, thr, alpha.astype(jnp.float32), beta.astype(jnp.float32),
-      margin.astype(jnp.float32))
-    return dist[:c, 0], rej[:c, 0].astype(bool), segs[:c, 0]
-
-
-# ---------------------------------------------------------------------------
-# manual-DMA variant: exited tiles skip the HBM fetch, not just the compute
-# ---------------------------------------------------------------------------
-
-
-def _skipdma_kernel(q_ref, x_hbm, thr_ref, alpha_ref, beta_ref, margin_ref,
-                    dist_ref, rej_ref, segs_ref,
-                    acc, alive, nseg, buf, sem,
-                    *, metric: str, n_segs: int, last_valid_seg: int,
-                    seg: int, tile_c: int):
-    i, s = pl.program_id(0), pl.program_id(1)
-    _init_scratch(s, acc, alive, nseg)
-
-    @pl.when(alive[:].max() > 0)
-    def _fetch_compute():
-        # the burst stream for this feature block is issued only while the
-        # tile is live — this is the skip_dma contract
-        dma = pltpu.make_async_copy(
-            x_hbm.at[pl.ds(i * tile_c, tile_c), pl.ds(s * seg, seg)], buf, sem)
-        dma.start()
-        dma.wait()
-        part = _part_distance(buf[:, :], q_ref[:, :], metric)
-        _accumulate_exit(part, s, thr_ref, alpha_ref, beta_ref, margin_ref,
-                         acc, alive, nseg, last_valid_seg)
-
-    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+    c = x.shape[0]
+    fn = _f32_fn(seg, metric, tile_c, interpret, False)
+    return tuple(o[:c] for o in fn(q, x, threshold, alpha, beta, margin))
 
 
 @functools.partial(jax.jit, static_argnames=("seg", "metric", "tile_c", "interpret"))
@@ -193,70 +276,27 @@ def fee_distance_skipdma_pallas(q, x, threshold, alpha, beta, margin, *,
                                 seg: int, metric: str = "l2", tile_c: int = 128,
                                 interpret: bool = True):
     """Same contract as :func:`fee_distance_pallas`, but ``x`` stays in HBM and
-    feature blocks are fetched with manual async copies gated on the tile-exit
-    flag: a fully-exited tile stops issuing DMAs, so the remaining bursts are
-    never read (the ``skip_dma`` open item from kernels/ROADMAP)."""
-    c, d = x.shape
-    n_segs = d // seg
-    assert n_segs * seg == d, (d, seg)
-    pad_c = (-c) % tile_c
-    if pad_c:
-        x = jnp.pad(x, ((0, pad_c), (0, 0)))
-    cp = c + pad_c
-    q2 = q.reshape(1, d)
-    thr = jnp.reshape(threshold, (1,)).astype(jnp.float32)
-
-    kern = functools.partial(_skipdma_kernel, metric=metric, n_segs=n_segs,
-                             last_valid_seg=n_segs - 1, seg=seg, tile_c=tile_c)
-    dist, rej, segs = pl.pallas_call(
-        kern,
-        grid=(cp // tile_c, n_segs),
-        in_specs=[
-            pl.BlockSpec((1, seg), lambda i, s: (0, s)),            # q
-            pl.BlockSpec(memory_space=pltpu.ANY),                   # x (HBM)
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # threshold
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # beta
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # margin
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile_c, 1), jnp.float32),   # acc
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # alive
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # nseg
-            pltpu.VMEM((tile_c, seg), jnp.float32), # feature-block landing buf
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=_compiler_params_cls()(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q2, x, thr, alpha.astype(jnp.float32), beta.astype(jnp.float32),
-      margin.astype(jnp.float32))
-    return dist[:c, 0], rej[:c, 0].astype(bool), segs[:c, 0]
+    feature blocks are fetched with manual async copies gated on the
+    tile-exit flag: a fully-exited tile stops issuing DMAs, so the remaining
+    bursts are never read."""
+    c = x.shape[0]
+    fn = _f32_fn(seg, metric, tile_c, interpret, True)
+    return tuple(o[:c] for o in fn(q, x, threshold, alpha, beta, margin))
 
 
 # ---------------------------------------------------------------------------
-# packed-input variant: dfloat_unpack fused into the FEE datapath
+# packed-input variants: dfloat_unpack fused into the FEE datapath
 # ---------------------------------------------------------------------------
 
 
 def _block_positions(cfg: dfl.DfloatConfig, seg: int):
-    """Per-FEE-block static decode positions and burst-aligned word ranges.
+    """Per-FEE-block static decode positions and word ranges.
 
     Returns ``blocks[k] = (positions, w0, w1)``: ``positions`` is the
     (word, bit-offset, segment) list of the block's features, ``[w0, w1)`` the
     word span that covers them (including the carry word of fields that span
-    a 32-bit word boundary — never a burst boundary, by layout rule 1).
+    a 32-bit word boundary — never a burst boundary, by layout rule 1),
+    widened to whole sublane tiles for the manual DMAs.
     """
     pos, w_words = dfl.feature_positions(cfg)
     d = cfg.dim
@@ -265,87 +305,83 @@ def _block_positions(cfg: dfl.DfloatConfig, seg: int):
     for k in range(d // seg):
         p = pos[k * seg : (k + 1) * seg]
         hi = max(wi + (1 if ofs + s.width > 32 else 0) for wi, ofs, s in p)
-        blocks.append((tuple(p), min(wi for wi, _, _ in p), hi + 1))
+        w0, w1 = _word_span(min(wi for wi, _, _ in p), hi + 1, w_words)
+        blocks.append((tuple(p), w0, w1))
     return blocks, w_words
 
 
-def _decode_block(xp, positions, w0: int):
-    """Decode one FEE feature block from packed words (slice-local at ``w0``).
-
-    All shifts/masks are static scalars — the software analogue of the preset
-    offset register driving the barrel shifter (paper Fig. 10d).
-    """
-    cols = []
-    for wi, ofs, s in positions:
-        v = xp[:, wi - w0] >> jnp.uint32(ofs)
-        if ofs + s.width > 32:
-            v = v | (xp[:, wi - w0 + 1] << jnp.uint32(32 - ofs))
-        fld = v & jnp.uint32((1 << s.width) - 1)
-        cols.append(dfl.decode_field_jnp(fld, s.n_exp, s.n_man, s.bias))
-    return jnp.stack(cols, axis=-1)                            # (TILE_C, seg)
+def _fetch_decode(src_hbm, b, i, positions, w0: int, w1: int, buf, sem,
+                  dec):
+    """Gated manual DMA of one block's word span of (query ``b``, tile
+    ``i``) from HBM, then decode it into ``dec``."""
+    tile_c = buf.shape[1]
+    dma = pltpu.make_async_copy(
+        src_hbm.at[b, pl.ds(w0, w1 - w0),
+                   pl.ds(pl.multiple_of(i * tile_c, tile_c), tile_c)],
+        buf.at[pl.ds(0, w1 - w0), :], sem)
+    dma.start()
+    dma.wait()
+    decode_rows(buf, positions, w0, dec)
 
 
 def _packed_kernel(q_ref, xp_ref, thr_ref, alpha_ref, beta_ref, margin_ref,
-                   dist_ref, rej_ref, segs_ref,
-                   acc, alive, nseg, *, metric: str, n_segs: int,
-                   last_valid_seg: int, blocks):
-    s = pl.program_id(1)
+                   dist_ref, rej_ref, segs_ref, acc, alive, nseg, dec,
+                   *, metric: str, n_segs: int, last_valid_seg: int, blocks):
+    b, s = pl.program_id(0), pl.program_id(2)
+    thr = thr_ref[b]
     _init_scratch(s, acc, alive, nseg)
     tile_alive = alive[:].max() > 0
 
     # the decode offsets of block k are compile-time constants, so the segment
     # loop is unrolled into one `pl.when` branch per block
-    for k, (positions, w0, _w1) in enumerate(blocks):
+    for k, (positions, _w0, _w1) in enumerate(blocks):
         @pl.when(tile_alive & (s == k))
         def _compute(k=k, positions=positions):
-            x = _decode_block(xp_ref[:, :], positions, 0)
-            part = _part_distance(x, q_ref[:, :], metric)
-            _accumulate_exit(part, k, thr_ref, alpha_ref, beta_ref, margin_ref,
-                             acc, alive, nseg, last_valid_seg)
+            decode_rows(xp_ref, positions, 0, dec)
+            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
+                             beta_ref, margin_ref, acc, alive, nseg,
+                             metric=metric, last_valid_seg=last_valid_seg)
 
     _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
 
 
 def _packed_skipdma_kernel(q_ref, xp_hbm, thr_ref, alpha_ref, beta_ref,
                            margin_ref, dist_ref, rej_ref, segs_ref,
-                           acc, alive, nseg, buf, sem,
+                           acc, alive, nseg, dec, buf, sem,
                            *, metric: str, n_segs: int, last_valid_seg: int,
-                           blocks, tile_c: int):
-    i, s = pl.program_id(0), pl.program_id(1)
+                           blocks):
+    b, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    thr = thr_ref[b]
     _init_scratch(s, acc, alive, nseg)
     tile_alive = alive[:].max() > 0
 
     for k, (positions, w0, w1) in enumerate(blocks):
         @pl.when(tile_alive & (s == k))
         def _fetch_compute(k=k, positions=positions, w0=w0, w1=w1):
-            dma = pltpu.make_async_copy(
-                xp_hbm.at[pl.ds(i * tile_c, tile_c), pl.ds(w0, w1 - w0)],
-                buf.at[:, pl.ds(0, w1 - w0)], sem)
-            dma.start()
-            dma.wait()
-            x = _decode_block(buf[:, :], positions, w0)
-            part = _part_distance(x, q_ref[:, :], metric)
-            _accumulate_exit(part, k, thr_ref, alpha_ref, beta_ref, margin_ref,
-                             acc, alive, nseg, last_valid_seg)
+            _fetch_decode(xp_hbm, b, i, positions, w0, w1, buf, sem, dec)
+            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
+                             beta_ref, margin_ref, acc, alive, nseg,
+                             metric=metric, last_valid_seg=last_valid_seg)
 
     _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
 
 
 def _tiered_kernel(q_ref, xc_ref, xr_hbm, thr_ref, alpha_ref, beta_ref,
                    margin_ref, dist_ref, rej_ref, segs_ref,
-                   acc, alive, nseg, buf, sem,
+                   acc, alive, nseg, dec, buf, sem,
                    *, metric: str, n_segs: int, last_valid_seg: int,
-                   c_blocks, r_blocks, tile_c: int):
+                   c_blocks, r_blocks):
     """Two-tier fused decode+FEE: resident coarse blocks + gated residual DMA.
 
     Blocks ``k < len(c_blocks)`` decode from the VMEM-resident coarse-tier
     tile (the hot prefix that makes the exit decision); blocks beyond the
-    boundary fetch their burst-aligned word span from the *residual* bitstream
-    in HBM with a ``make_async_copy`` gated on the tile-exit flag — a tile
-    whose lanes all exited inside the coarse tier never issues a residual
-    fetch, so cold-tier traffic moves only for survivors.
+    boundary fetch their word span from the *residual* bitstream in HBM with
+    a ``make_async_copy`` gated on the tile-exit flag — a tile whose lanes all
+    exited inside the coarse tier never issues a residual fetch, so cold-tier
+    traffic moves only for survivors.
     """
-    i, s = pl.program_id(0), pl.program_id(1)
+    b, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    thr = thr_ref[b]
     _init_scratch(s, acc, alive, nseg)
     tile_alive = alive[:].max() > 0
     n_coarse = len(c_blocks)
@@ -353,26 +389,73 @@ def _tiered_kernel(q_ref, xc_ref, xr_hbm, thr_ref, alpha_ref, beta_ref,
     for k, (positions, _w0, _w1) in enumerate(c_blocks):
         @pl.when(tile_alive & (s == k))
         def _compute(k=k, positions=positions):
-            x = _decode_block(xc_ref[:, :], positions, 0)
-            part = _part_distance(x, q_ref[:, :], metric)
-            _accumulate_exit(part, k, thr_ref, alpha_ref, beta_ref, margin_ref,
-                             acc, alive, nseg, last_valid_seg)
+            decode_rows(xc_ref, positions, 0, dec)
+            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
+                             beta_ref, margin_ref, acc, alive, nseg,
+                             metric=metric, last_valid_seg=last_valid_seg)
 
     for j, (positions, w0, w1) in enumerate(r_blocks):
         k = n_coarse + j
         @pl.when(tile_alive & (s == k))
         def _fetch_compute(k=k, positions=positions, w0=w0, w1=w1):
-            dma = pltpu.make_async_copy(
-                xr_hbm.at[pl.ds(i * tile_c, tile_c), pl.ds(w0, w1 - w0)],
-                buf.at[:, pl.ds(0, w1 - w0)], sem)
-            dma.start()
-            dma.wait()
-            x = _decode_block(buf[:, :], positions, w0)
-            part = _part_distance(x, q_ref[:, :], metric)
-            _accumulate_exit(part, k, thr_ref, alpha_ref, beta_ref, margin_ref,
-                             acc, alive, nseg, last_valid_seg)
+            _fetch_decode(xr_hbm, b, i, positions, w0, w1, buf, sem, dec)
+            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
+                             beta_ref, margin_ref, acc, alive, nseg,
+                             metric=metric, last_valid_seg=last_valid_seg)
 
     _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+
+
+def _landing_buf(blocks, tile_c: int):
+    return pltpu.VMEM((max(w1 - w0 for _, w0, w1 in blocks), tile_c),
+                      jnp.uint32)
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_fn(cfg: dfl.DfloatConfig, seg: int, metric: str, tile_c: int,
+               interpret: bool, skip_dma: bool):
+    blocks, w = _block_positions(cfg, seg)
+    common = dict(metric=metric, blocks=tuple(blocks))
+    dec = pltpu.VMEM((seg, tile_c), jnp.float32)            # decoded block
+
+    def batched(q, xp, thr, alpha, beta, margin):
+        assert xp.shape[2] == w, (xp.shape, w)
+        if skip_dma:
+            kern = functools.partial(_packed_skipdma_kernel, **common)
+            xp_spec = pl.BlockSpec(memory_space=pl.ANY)
+            scratch = [dec, _landing_buf(blocks, tile_c),
+                       pltpu.SemaphoreType.DMA]
+        else:
+            kern = functools.partial(_packed_kernel, **common)
+            xp_spec = pl.BlockSpec((None, w, tile_c), lambda b, i, s: (b, 0, i))
+            scratch = [dec]
+        return _fee_call(kern, q, [_feature_major(xp, tile_c)], [xp_spec],
+                         thr, alpha, beta, margin, seg=seg, tile_c=tile_c,
+                         scratch=scratch, interpret=interpret)
+    return _one_query(batched, 3)
+
+
+@functools.lru_cache(maxsize=None)
+def _tiered_fn(coarse_cfg: dfl.DfloatConfig, resid_cfg: dfl.DfloatConfig,
+               seg: int, metric: str, tile_c: int, interpret: bool):
+    c_blocks, wc = _block_positions(coarse_cfg, seg)
+    r_blocks, wr = _block_positions(resid_cfg, seg)
+    kern = functools.partial(_tiered_kernel, metric=metric,
+                             c_blocks=tuple(c_blocks), r_blocks=tuple(r_blocks))
+
+    def batched(q, xc, xr, thr, alpha, beta, margin):
+        assert xc.shape[2] == wc and xr.shape[2] == wr, (xc.shape, xr.shape)
+        x_specs = [
+            pl.BlockSpec((None, wc, tile_c), lambda b, i, s: (b, 0, i)),  # coarse
+            pl.BlockSpec(memory_space=pl.ANY),                  # resid (HBM)
+        ]
+        scratch = [pltpu.VMEM((seg, tile_c), jnp.float32),         # decoded
+                   _landing_buf(r_blocks, tile_c), pltpu.SemaphoreType.DMA]
+        return _fee_call(kern, q, [_feature_major(xc, tile_c),
+                                   _feature_major(xr, tile_c)], x_specs,
+                         thr, alpha, beta, margin, seg=seg, tile_c=tile_c,
+                         scratch=scratch, interpret=interpret)
+    return _one_query(batched, 4)
 
 
 @functools.partial(jax.jit, static_argnames=("coarse_cfg", "resid_cfg", "seg",
@@ -402,62 +485,9 @@ def fee_distance_tiered_pallas(q, xc, xr, threshold, alpha, beta, margin, *,
         return fee_distance_packed_pallas(
             q, xc, threshold, alpha, beta, margin, dfloat_cfg=coarse_cfg,
             seg=seg, metric=metric, tile_c=tile_c, interpret=interpret)
-    c, wc = xc.shape
-    d = coarse_cfg.dim + resid_cfg.dim
-    n_segs = d // seg
-    assert n_segs * seg == d, (d, seg)
-    c_blocks, wc_words = _block_positions(coarse_cfg, seg)
-    r_blocks, wr_words = _block_positions(resid_cfg, seg)
-    assert wc == wc_words and xr.shape[1] == wr_words, (xc.shape, xr.shape)
-    pad_c = (-c) % tile_c
-    if pad_c:
-        xc = jnp.pad(xc, ((0, pad_c), (0, 0)))
-        xr = jnp.pad(xr, ((0, pad_c), (0, 0)))
-    cp = c + pad_c
-    q2 = q.reshape(1, d)
-    thr = jnp.reshape(threshold, (1,)).astype(jnp.float32)
-
-    kern = functools.partial(
-        _tiered_kernel, metric=metric, n_segs=n_segs,
-        last_valid_seg=n_segs - 1, c_blocks=tuple(c_blocks),
-        r_blocks=tuple(r_blocks), tile_c=tile_c)
-    dist, rej, segs = pl.pallas_call(
-        kern,
-        grid=(cp // tile_c, n_segs),
-        in_specs=[
-            pl.BlockSpec((1, seg), lambda i, s: (0, s)),            # q
-            pl.BlockSpec((tile_c, wc), lambda i, s: (i, 0)),        # coarse
-            pl.BlockSpec(memory_space=pltpu.ANY),                   # resid (HBM)
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # threshold
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # beta
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # margin
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile_c, 1), jnp.float32),   # acc
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # alive
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # nseg
-            pltpu.VMEM((tile_c, max(w1 - w0 for _, w0, w1 in r_blocks)),
-                       jnp.uint32),                 # residual landing buf
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=_compiler_params_cls()(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q2, xc, xr, thr, alpha.astype(jnp.float32), beta.astype(jnp.float32),
-      margin.astype(jnp.float32))
-    return dist[:c, 0], rej[:c, 0].astype(bool), segs[:c, 0]
+    c = xc.shape[0]
+    fn = _tiered_fn(coarse_cfg, resid_cfg, seg, metric, tile_c, interpret)
+    return tuple(o[:c] for o in fn(q, xc, xr, threshold, alpha, beta, margin))
 
 
 @functools.partial(jax.jit, static_argnames=("dfloat_cfg", "seg", "metric",
@@ -472,67 +502,10 @@ def fee_distance_packed_pallas(q, xp, threshold, alpha, beta, margin, *,
     bytes cross HBM; decoded features exist only in VMEM, one block at a time.
     Results are bit-compatible with ``fee_distance_pallas`` over
     ``dfloat.emulate_db`` data.  ``skip_dma=True`` keeps the bitstream in HBM
-    and fetches each live block's burst-aligned word span with a manual async
-    copy — exited tiles skip the remaining packed bursts entirely.
+    and fetches each live block's word span with a manual async copy —
+    exited tiles skip the remaining packed bursts entirely.
     """
-    c, w = xp.shape
-    d = dfloat_cfg.dim
-    n_segs = d // seg
-    assert n_segs * seg == d, (d, seg)
-    blocks, w_words = _block_positions(dfloat_cfg, seg)
-    assert w == w_words, (w, w_words)
-    pad_c = (-c) % tile_c
-    if pad_c:
-        xp = jnp.pad(xp, ((0, pad_c), (0, 0)))
-    cp = c + pad_c
-    q2 = q.reshape(1, d)
-    thr = jnp.reshape(threshold, (1,)).astype(jnp.float32)
-
-    common = dict(metric=metric, n_segs=n_segs, last_valid_seg=n_segs - 1,
-                  blocks=tuple(blocks))
-    if skip_dma:
-        kern = functools.partial(_packed_skipdma_kernel, tile_c=tile_c, **common)
-        xp_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        scratch_extra = [
-            pltpu.VMEM((tile_c, max(w1 - w0 for _, w0, w1 in blocks)),
-                       jnp.uint32),                       # word-span landing buf
-            pltpu.SemaphoreType.DMA,
-        ]
-    else:
-        kern = functools.partial(_packed_kernel, **common)
-        xp_spec = pl.BlockSpec((tile_c, w), lambda i, s: (i, 0))
-        scratch_extra = []
-    dist, rej, segs = pl.pallas_call(
-        kern,
-        grid=(cp // tile_c, n_segs),
-        in_specs=[
-            pl.BlockSpec((1, seg), lambda i, s: (0, s)),            # q
-            xp_spec,                                                # packed x
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # threshold
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # alpha
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # beta
-            pl.BlockSpec(memory_space=pltpu.SMEM),                  # margin
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-            pl.BlockSpec((tile_c, 1), lambda i, s: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cp, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile_c, 1), jnp.float32),   # acc
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # alive
-            pltpu.VMEM((tile_c, 1), jnp.int32),     # nseg
-            *scratch_extra,
-        ],
-        compiler_params=_compiler_params_cls()(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q2, xp, thr, alpha.astype(jnp.float32), beta.astype(jnp.float32),
-      margin.astype(jnp.float32))
-    return dist[:c, 0], rej[:c, 0].astype(bool), segs[:c, 0]
+    assert dfloat_cfg.dim == q.shape[0], (dfloat_cfg.dim, q.shape)
+    c = xp.shape[0]
+    fn = _packed_fn(dfloat_cfg, seg, metric, tile_c, interpret, skip_dma)
+    return tuple(o[:c] for o in fn(q, xp, threshold, alpha, beta, margin))

@@ -1,4 +1,6 @@
 import os
+# a CPU shape tool: 512 fake host devices, never the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
@@ -26,14 +28,14 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs as C
-from repro.distributed import compat
 from repro.distributed import sharding as sh
 from repro.launch import mesh as mesh_mod
 from repro.models.registry import get_model
 from repro.training import OptConfig, optim
 from repro.training.train_step import TrainState, make_train_step
+from repro.utils import CACHE_DIR
 
-OUT_DIR = Path("/root/repo/.cache/dryrun")
+OUT_DIR = CACHE_DIR / "dryrun"
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -245,7 +247,7 @@ def build_retrieval_cell(mesh, n: int = 1_000_000_000, d: int = 128,
 
 def analyze(jitted, args_abs, mesh, meta: dict) -> dict:
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args_abs)
         compiled = lowered.compile()
     t1 = time.time()
@@ -289,7 +291,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force=False) -> dict:
     try:
         if arch == "retrieval-bigann1b":
             searcher, args_abs = build_retrieval_cell(mesh)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = searcher.lower(*args_abs)
                 compiled = lowered.compile()
             mem = compiled.memory_analysis()

@@ -2,9 +2,9 @@
 cache.  Usage: PYTHONPATH=src python -m repro.launch.report [--markdown]"""
 import argparse
 import json
-from pathlib import Path
+from repro.utils import CACHE_DIR, REPO_ROOT
 
-DRYRUN_DIR = Path("/root/repo/.cache/dryrun")
+DRYRUN_DIR = CACHE_DIR / "dryrun"
 
 
 def load(mesh: str):
@@ -57,7 +57,7 @@ def main():
     print(dryrun_table(args.markdown))
     print()
     import sys
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, str(REPO_ROOT))
     from benchmarks import roofline
     print("== Roofline (single-pod) ==")
     roofline.report("single")

@@ -40,6 +40,9 @@ def main(argv=None):
 
     from repro.data.synthetic import make_dataset
     from repro.index import Index, IndexSpec, SearchParams
+    from repro.serve import enable_compilation_cache
+
+    print(f"compilation cache: {enable_compilation_cache()}")
 
     db = make_dataset(args.dataset)
     print(f"dataset {db.name}: {db.n} x {db.dim} ({db.metric})")

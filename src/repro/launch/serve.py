@@ -5,8 +5,9 @@
 
 Drives ``repro.serve`` end to end: builds (or loads) an index, wraps it in a
 MutableIndex when ``--mutate`` asks for live churn, starts the server
-(compiling the program lattice, optionally against a persistent compilation
-cache for warm restarts), replays an open-loop arrival process, and prints /
+(compiling the program lattice against the persistent compilation cache,
+``$JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.cache/jax``, for warm
+restarts), replays an open-loop arrival process, and prints /
 writes the latency, goodput and hot-swap accounting.  ``--check-*`` flags
 turn the run into a gate (non-zero exit on violation) for CI.
 
@@ -52,8 +53,6 @@ def _serve_main(argv):
                          "per second of live churn; 0 = static index")
     ap.add_argument("--mutate-every-s", type=float, default=1.0)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--cache-dir", default=None,
-                    help="persistent jit compilation cache (warm start)")
     ap.add_argument("--report", default=None, help="write JSON report here")
     ap.add_argument("--trace", action="store_true",
                     help="record request spans (bounded ring buffer)")
@@ -69,12 +68,12 @@ def _serve_main(argv):
                     help="exit 1 when p99 exceeds this bound")
     args = ap.parse_args(argv)
 
-    if args.cache_dir:
-        # must precede the process's first jit compile (JAX memoises cache
-        # availability per backend at first compilation)
-        from repro.serve import enable_compilation_cache
+    # persistent jit cache (warm start); must precede the process's first
+    # jit compile (JAX memoises cache availability per backend at first
+    # compilation)
+    from repro.serve import enable_compilation_cache
 
-        enable_compilation_cache(args.cache_dir)
+    print(f"compilation cache: {enable_compilation_cache()}", flush=True)
 
     import numpy as np
 

@@ -57,16 +57,15 @@ def main(argv=None):
     else:
         shape = (1, ndev)
     mesh = jax.make_mesh(shape, ("data", "model")[: len(shape)] if len(shape) == 2
-                         else ("pod", "data", "model"))
-
-    from repro.distributed import compat
+                         else ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
     pipe = TokenPipeline(cfg.vocab, args.batch, args.seq, seed=1,
                          frontend=cfg.frontend, frontend_tokens=cfg.frontend_tokens,
                          d_model=cfg.d_model, encdec=cfg.is_encdec,
                          decoder_len=min(cfg.decoder_len_train, args.seq))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = api.init(jax.random.key(0))
         pspecs = sh.param_specs(api.abstract_params(), mesh)
         params = jax.tree.map(lambda x, s: jax.device_put(x, jax.NamedSharding(mesh, s)),
@@ -93,6 +92,11 @@ def main(argv=None):
         writer = None
         for step in range(start, args.steps):
             if step == args.simulate_failure:
+                # the simulated crash hits the step loop: a checkpoint whose
+                # async write is already in flight still lands (exiting would
+                # kill its daemon writer thread mid-write)
+                if writer is not None:
+                    writer.join()
                 print(f"[failure] simulated crash at step {step}", flush=True)
                 sys.exit(17)
             batch = {k: jnp.asarray(v) for k, v in pipe.batch_at(step).items()}

@@ -52,11 +52,6 @@ class ServeConfig:
     watchdog_stall_s: float = 5.0       # heartbeat age that declares the
                                         # batcher wedged (hung device call)
 
-    # -- warmup --------------------------------------------------------------
-    compilation_cache_dir: str | None = None   # persistent jit cache (warm
-                                               # start); must be set before
-                                               # the process's first compile
-
     def __post_init__(self):
         if tuple(sorted(self.ef_buckets)) != tuple(self.ef_buckets):
             raise ValueError("ef_buckets must be sorted ascending")

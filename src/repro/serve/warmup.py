@@ -1,12 +1,14 @@
 """Warm-start: persistent jit cache + eager compilation of the program set.
 
-``enable_compilation_cache`` points JAX's persistent compilation cache at a
-directory (the maxtext idiom) so a restarted server deserialises its
-executables instead of re-tracing them.  JAX binds cache availability at
+``enable_compilation_cache`` turns on JAX's persistent compilation cache
+(the maxtext idiom) so a restarted server deserialises its executables
+instead of re-tracing them.  The directory is ``$JAX_COMPILATION_CACHE_DIR``
+when that is set and otherwise the fixed ``<checkout>/.cache/jax``
+(``repro.utils.compilation_cache_dir``).  JAX binds cache availability at
 the process's first jit compilation, so the helper resets that decision
 after pointing the config at the directory — safe to call any time before
 ``Server.start()``, but cheapest first thing (nothing to re-decide).  The
-serve CLI calls it before building anything.
+serve and search CLIs call it before building anything.
 
 ``compile_programs`` then touches every ``(ef bucket x storage x batch
 bucket)`` program cell with dummy queries, timing each run to seed the
@@ -22,14 +24,18 @@ import time
 import numpy as np
 
 
-def enable_compilation_cache(cache_dir) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` and make
-    sure the next compilation actually uses it (JAX freezes the enablement
-    decision at the first compile; this resets it)."""
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache in
+    :func:`repro.utils.compilation_cache_dir` and make sure the next
+    compilation actually uses it (JAX freezes the enablement decision at the
+    first compile; this resets it).  Returns the directory."""
     import jax
     from jax._src import compilation_cache
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    from repro.utils import compilation_cache_dir
+
+    cache_dir = compilation_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     # default thresholds skip small/fast CPU executables; serving programs
     # must all persist for the warm-start win to materialise
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -38,6 +44,7 @@ def enable_compilation_cache(cache_dir) -> None:
     # merely importing index/serve modules can compile something tiny — drop
     # back to the uninitialized state so the next compile picks up the dir
     compilation_cache.reset_cache()
+    return cache_dir
 
 
 def compile_programs(snapshot, cfg, model=None, dim: int | None = None,

@@ -1,6 +1,7 @@
 """Distributed correctness on 8 fake devices (subprocess — the main pytest
 process is pinned to 1 CPU device): DaM-sharded retrieval equivalence,
 sharded decode equivalence, compressed psum, sharding rule sanity."""
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -8,9 +9,9 @@ from pathlib import Path
 import pytest
 
 SRC = str(Path(__file__).parent.parent / "src")
-ENV = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin", "HOME": "/root",
-       "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-       "REPRO_CACHE": "/root/repo/.cache"}
+ENV = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+       "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu",
+       "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
 
 
 def _run(code: str, timeout=560):
@@ -30,7 +31,8 @@ from repro.index import Index, IndexSpec, SearchParams
 
 db = make_dataset("unit")
 idx = Index.build(db, IndexSpec.for_db(db, m=8, dfloat_recall_target=None))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 params = SearchParams(ef=32, k=10, use_dfloat=False)
 sharded = idx.searcher("sharded", params, mesh=mesh)(db.queries[:16])
 ref = idx.searcher("local", params)(db.queries[:16])
@@ -64,9 +66,9 @@ for t in range(4, 8):
     ref_logits, cache = api.decode(params, cache, toks[:, t])
 
 # sharded: seq-sharded KV over model axis
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-from repro.distributed import compat
-with compat.set_mesh(mesh):
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     pspecs = sh.param_specs(api.abstract_params(), mesh)
     params_s = jax.tree.map(lambda x, s: jax.device_put(x, jax.NamedSharding(mesh, s)),
                             params, pspecs)
@@ -91,7 +93,8 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.training.compress import GradCompressor
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 comp = GradCompressor(bits=8)
 g_global = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64)), jnp.float32)
 
@@ -101,10 +104,10 @@ def body(g):
     deq, err = comp.compressed_psum(grads, err, "data")
     return deq["w"][None], err["w"][None]
 
-from repro.distributed import compat
-with compat.set_mesh(mesh):
-    deq, err = compat.shard_map(body, mesh=mesh, in_specs=(P("data", None),),
-                                out_specs=(P("data", None), P("data", None)))(g_global)
+with jax.set_mesh(mesh):
+    deq, err = jax.shard_map(body, mesh=mesh, in_specs=(P("data", None),),
+                             out_specs=(P("data", None), P("data", None)),
+                             check_vma=False)(g_global)
 true_mean = np.asarray(g_global).mean(0)
 got = np.asarray(deq)[0]
 rel = np.abs(got - true_mean).max() / (np.abs(true_mean).max() + 1e-9)

@@ -1,4 +1,5 @@
 """Fault tolerance: checkpoint roundtrip, failure/resume, elastic reshard."""
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -46,7 +47,7 @@ def _run_train(args, timeout=560):
         [sys.executable, "-m", "repro.launch.train", *args],
         capture_output=True, text=True, timeout=timeout,
         env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"})
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"})
 
 
 @pytest.mark.slow
@@ -88,9 +89,9 @@ mode, path = sys.argv[1], sys.argv[2]
 cfg = C.get_smoke("llama3.2-1b")
 api = get_model(cfg)
 ndev = len(jax.devices())
-mesh = jax.make_mesh((1, ndev), ("data", "model"))
-from repro.distributed import compat
-with compat.set_mesh(mesh):
+mesh = jax.make_mesh((1, ndev), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     pspecs = sh.param_specs(api.abstract_params(), mesh)
     if mode == "save":
         params = api.init(jax.random.key(0))
@@ -103,7 +104,8 @@ with compat.set_mesh(mesh):
         tot = sum(float(jnp.sum(jnp.abs(x).astype(jnp.float32))) for x in jax.tree.leaves(params))
         print("RESTORED", ndev, f"{tot:.4f}")
 """ % SRC
-    env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin", "HOME": "/root"}
+    env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+           "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"}
     r1 = subprocess.run([sys.executable, "-c", code, "save", str(tmp_path / "ck")],
                         capture_output=True, text=True, timeout=560,
                         env={**env, "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})

@@ -251,8 +251,9 @@ def test_freeze_stamps_n_rows(unit_db, unit_index):
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
 def test_compilation_cache_persists(tmp_path):
-    """enable_compilation_cache must make jit executables land on disk even
-    when something compiled before it ran (fresh interpreter per phase)."""
+    """enable_compilation_cache must make jit executables land on disk, in
+    $JAX_COMPILATION_CACHE_DIR, even when something compiled before it ran
+    (fresh interpreter per phase)."""
     import subprocess
     import sys
 
@@ -260,12 +261,15 @@ def test_compilation_cache_persists(tmp_path):
 import jax, jax.numpy as jnp                      # compile before enabling
 jax.jit(lambda x: x + 1)(jnp.zeros(8)).block_until_ready()
 from repro.serve import enable_compilation_cache
-enable_compilation_cache({d!r})
+print(enable_compilation_cache())
 jax.jit(lambda x: x * 3 - 1)(jnp.zeros(128)).block_until_ready()
-""".format(d=str(tmp_path / "cc"))
-    subprocess.run([sys.executable, "-c", prog], check=True,
-                   env=_env(), timeout=300)
-    entries = list((tmp_path / "cc").glob("*"))
+"""
+    d = tmp_path / "cc"
+    out = subprocess.run([sys.executable, "-c", prog], check=True,
+                         capture_output=True, text=True, timeout=300,
+                         env=dict(_env(), JAX_COMPILATION_CACHE_DIR=str(d)))
+    assert out.stdout.split()[-1] == str(d)
+    entries = list(d.glob("*"))
     assert entries, "no compilation cache entries were persisted"
 
 

@@ -8,6 +8,7 @@ Subprocess (8 fake XLA devices, same harness as tests/test_distributed.py):
 bit-parity of the ``sharded`` backend against ``local`` — identical ids AND
 dists — across metric (l2, ip), storage (f32, packed), shard counts, with
 expand > 1 and with tombstoned rows; plus overlap-vs-sync agreement."""
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -16,9 +17,9 @@ import numpy as np
 import pytest
 
 SRC = str(Path(__file__).parent.parent / "src")
-ENV = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin", "HOME": "/root",
-       "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-       "REPRO_CACHE": "/root/repo/.cache"}
+ENV = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+       "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu",
+       "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
 
 
 def _run(code: str, timeout=560):

@@ -1,0 +1,147 @@
+"""Compile rehearsal for the TPU: every Pallas kernel ``kernels/ops.py`` can
+dispatch to is compiled — not run — for one chip of a described v5e:2x2
+topology, at the real widths of the sift/bigann (D=128) and gist (D=960)
+shapes, vmapped over a query batch as the search loop calls it.  The whole
+local search program is compiled once per storage as well.
+
+Interpret mode (every other kernel test) cannot see what the chip's compiler
+refuses: block shapes off the (8, 128) tiling, unsupported in-kernel layout
+casts, VMEM overflow.  These tests can, with no chip attached.
+
+The topology is described inside a module fixture, never while a module is
+imported: only one process at a time may load the TPU library, and it keeps
+it until it exits.  Keep every such test in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import dfloat as dfl
+from repro.core import search as search_mod
+from repro.core.fee import FeeParams
+from repro.kernels import fee_distance as fd
+from repro.kernels import ops as kops
+from repro.kernels.dfloat_unpack import dfloat_unpack_pallas
+
+SEG = 16
+QUERIES = 32                  # the largest serving batch bucket
+LANES = search_mod.compact_width(16, 4)    # frontier lanes per hop at M=16
+# Dfloat layouts of the shapes Algorithm 1 picks: several widths per row,
+# fields that straddle 32-bit words (21, 18 and 14 bits)
+LAYOUTS = {128: [(21, 6, 48), (14, 5, 80)],
+           960: [(18, 6, 320), (14, 5, 320), (12, 4, 320)]}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _dfloat(d):
+    cfg = dfl.make_config(d, LAYOUTS[d])
+    return cfg, dfl.split_config(cfg, d // 2)
+
+
+def _kernel_case(variant, d):
+    """(single-query kernel fn, candidate operand shapes/dtypes)."""
+    cfg, (ccfg, rcfg) = _dfloat(d)
+    w = dfl.packed_words(cfg)
+    kw = dict(seg=SEG, interpret=False)
+    if variant == "f32":
+        return (lambda q, x, *f: fd.fee_distance_pallas(q, x, *f, **kw),
+                [((LANES, d), jnp.float32)])
+    if variant == "f32_skip_dma":
+        return (lambda q, x, *f: fd.fee_distance_skipdma_pallas(q, x, *f, **kw),
+                [((LANES, d), jnp.float32)])
+    if variant in ("packed", "packed_skip_dma"):
+        skip = variant == "packed_skip_dma"
+        return (lambda q, x, *f: fd.fee_distance_packed_pallas(
+                    q, x, *f, dfloat_cfg=cfg, skip_dma=skip, **kw),
+                [((LANES, w), jnp.uint32)])
+    assert variant == "tiered"
+    return (lambda q, xc, xr, *f: fd.fee_distance_tiered_pallas(
+                q, xc, xr, *f, coarse_cfg=ccfg, resid_cfg=rcfg, **kw),
+            [((LANES, dfl.packed_words(ccfg)), jnp.uint32),
+             ((LANES, dfl.packed_words(rcfg)), jnp.uint32)])
+
+
+@pytest.mark.parametrize("d", sorted(LAYOUTS))
+@pytest.mark.parametrize("variant", ["f32", "f32_skip_dma", "packed",
+                                     "packed_skip_dma", "tiered"])
+def test_fee_kernel_compiles_for_tpu(one_chip, variant, d):
+    fn, xs = _kernel_case(variant, d)
+    n_segs = d // SEG
+
+    def batch(q, *args):
+        # threshold per query, FEE parameters shared (as in _search_batch)
+        *cands, thr, alpha, beta, margin = args
+        return jax.vmap(fn, in_axes=(0,) * (len(cands) + 2) + (None,) * 3)(
+            q, *cands, thr, alpha, beta, margin)
+
+    args = [_sds(one_chip, (QUERIES, d), jnp.float32),
+            *(_sds(one_chip, (QUERIES, *s), t) for s, t in xs),
+            _sds(one_chip, (QUERIES,), jnp.float32),
+            *(_sds(one_chip, (n_segs,), jnp.float32) for _ in range(3))]
+    compiled = jax.jit(batch).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("d", sorted(LAYOUTS))
+def test_dfloat_unpack_compiles_for_tpu(one_chip, d):
+    cfg, _ = _dfloat(d)
+    fn = jax.vmap(lambda p: dfloat_unpack_pallas(p, cfg, interpret=False))
+    compiled = jax.jit(fn).lower(
+        _sds(one_chip, (QUERIES, LANES, dfl.packed_words(cfg)), jnp.uint32)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("storage", ["f32", "packed", "tiered"])
+def test_search_program_compiles_for_tpu(one_chip, monkeypatch, storage):
+    """The whole jitted local search (vmap over queries of the hop
+    while_loop) with ``fee_backend="auto"`` dispatching to the kernels."""
+    monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+    n, d, m = 4099, 128, 16
+    cfg, tiers = _dfloat(d)
+    if storage == "f32":
+        vectors, dcfg = _sds(one_chip, (n, d), jnp.float32), None
+    elif storage == "packed":
+        vectors = _sds(one_chip, (n, dfl.packed_words(cfg)), jnp.uint32)
+        dcfg = cfg
+    else:
+        vectors = tuple(_sds(one_chip, (n, dfl.packed_words(c)), jnp.uint32)
+                        for c in tiers)
+        dcfg = tiers
+    seg_vec = _sds(one_chip, (d // SEG,), jnp.float32)
+    compiled = search_mod._search_batch.lower(
+        vectors, _sds(one_chip, (n, m), jnp.int32),
+        FeeParams(seg_vec, seg_vec, seg_vec), None,
+        _sds(one_chip, (QUERIES, d), jnp.float32),
+        _sds(one_chip, (QUERIES,), jnp.int32),
+        cfg=search_mod.SearchConfig(ef=64, k=10, seg=SEG, use_fee=True,
+                                    storage=storage),
+        trace=False, dfl_cfg=dcfg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
