@@ -2,6 +2,7 @@
 cache.  Usage: PYTHONPATH=src python -m repro.launch.report [--markdown]"""
 import argparse
 import json
+
 from repro.utils import CACHE_DIR, REPO_ROOT
 
 DRYRUN_DIR = CACHE_DIR / "dryrun"

@@ -3,8 +3,8 @@
 ``enable_compilation_cache`` turns on JAX's persistent compilation cache
 (the maxtext idiom) so a restarted server deserialises its executables
 instead of re-tracing them.  The directory is ``$JAX_COMPILATION_CACHE_DIR``
-when that is set and otherwise the fixed ``<checkout>/.cache/jax``
-(``repro.utils.compilation_cache_dir``).  JAX binds cache availability at
+when that is set and otherwise the fixed ``<checkout>/.cache/jax``.  JAX
+binds cache availability at
 the process's first jit compilation, so the helper resets that decision
 after pointing the config at the directory — safe to call any time before
 ``Server.start()``, but cheapest first thing (nothing to re-decide).  The
@@ -26,15 +26,20 @@ import numpy as np
 
 def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache in
-    :func:`repro.utils.compilation_cache_dir` and make sure the next
-    compilation actually uses it (JAX freezes the enablement decision at the
-    first compile; this resets it).  Returns the directory."""
+    ``$JAX_COMPILATION_CACHE_DIR``, or else in ``<checkout>/.cache/jax``, and
+    make sure the next compilation actually uses it (JAX freezes the
+    enablement decision at the first compile; this resets it).  Returns the
+    directory."""
+    import os
+
     import jax
     from jax._src import compilation_cache
 
-    from repro.utils import compilation_cache_dir
+    from repro.utils import REPO_ROOT
 
-    cache_dir = compilation_cache_dir()
+    # a fixed path, so that the next run of this checkout finds the cache
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or str(REPO_ROOT / ".cache" / "jax"))
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     # default thresholds skip small/fast CPU executables; serving programs
     # must all persist for the warm-start win to materialise

@@ -15,14 +15,6 @@ REPO_ROOT = Path(__file__).resolve().parents[2]
 CACHE_DIR = Path(os.environ.get("REPRO_CACHE", REPO_ROOT / ".cache"))
 
 
-def compilation_cache_dir() -> str:
-    """Where JAX's persistent compilation cache lives: ``$JAX_COMPILATION_CACHE_DIR``
-    when set, else the fixed ``<checkout>/.cache/jax`` (the path is part of
-    the cache key, so it must not move between runs)."""
-    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or str(REPO_ROOT / ".cache" / "jax"))
-
-
 def cache_path(key: str, suffix: str = ".npz") -> Path:
     CACHE_DIR.mkdir(parents=True, exist_ok=True)
     h = hashlib.sha1(key.encode()).hexdigest()[:16]
