@@ -55,6 +55,7 @@ from repro.core import dfloat as dfl
 from repro.core import fee as fee_mod
 from repro.core.fee import FeeParams
 from repro.kernels import ops as kops
+from repro.obs import tracer
 
 BIG = jnp.float32(3.0e38)
 
@@ -263,54 +264,62 @@ def _hop_body(state, vectors, adj, q, fee: FeeParams | None, cfg: SearchConfig,
     beam_ids, beam_d, expanded, visited = state
     ef = beam_ids.shape[0]
     e, m = min(cfg.expand, ef), adj.shape[1]
-    nodes, sel, expanded = pop_frontier(beam_ids, beam_d, expanded, e)
+    with jax.named_scope("hop.frontier"):
+        nodes, sel, expanded = pop_frontier(beam_ids, beam_d, expanded, e)
 
-    # ---- one fused gather of all E neighbor lists
-    nbrs = adj[jnp.maximum(nodes, 0)].reshape(e * m)       # (E*M,)
-    valid = (nbrs >= 0) & jnp.repeat(sel, m)
-    safe = jnp.maximum(nbrs, 0)
-    w = safe >> 5
-    bit = (jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
-    seen = (visited[w] & bit) != 0
-    fresh = valid & ~seen & first_occurrence_mask(safe, valid)
+        # ---- one fused gather of all E neighbor lists
+        nbrs = adj[jnp.maximum(nodes, 0)].reshape(e * m)       # (E*M,)
+        valid = (nbrs >= 0) & jnp.repeat(sel, m)
+        safe = jnp.maximum(nbrs, 0)
+        w = safe >> 5
+        bit = (jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
+        seen = (visited[w] & bit) != 0
+        fresh = valid & ~seen & first_occurrence_mask(safe, valid)
 
-    # ---- fresh-first frontier compaction (expand > 1): after the visited/
-    # dedup filter, typically well under half the E*M slots survive, so the
-    # downstream gather, scoring, visited scatter and beam merge run on an
-    # L = E*M/2 budget instead of the full batch.  top_k on the boolean mask
-    # is a *stable* partition (ties keep pop order) and costs far less than a
-    # sort on XLA CPU.  Overflowing fresh candidates are dropped *unmarked*:
-    # they stay discoverable through other parents on later hops (recall
-    # parity holds; see tests/test_expand.py).
-    if e > 1:
-        l = compact_width(m, e, cfg.compact)
-        _, keep = jax.lax.top_k(fresh.astype(jnp.float32), l)
-        nbrs, safe, fresh = nbrs[keep], safe[keep], fresh[keep]
-        w, bit = safe >> 5, (jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
-        src = keep // m                                    # parent pop slot
-    else:
-        src = jnp.arange(e * m, dtype=jnp.int32) // m
-    visited = visited.at[w].add(jnp.where(fresh, bit, jnp.uint32(0)))
+        # ---- fresh-first frontier compaction (expand > 1): after the
+        # visited/dedup filter, typically well under half the E*M slots
+        # survive, so the downstream gather, scoring, visited scatter and
+        # beam merge run on an L = E*M/2 budget instead of the full batch.
+        # top_k on the boolean mask is a *stable* partition (ties keep pop
+        # order) and costs far less than a sort on XLA CPU.  Overflowing
+        # fresh candidates are dropped *unmarked*: they stay discoverable
+        # through other parents on later hops (recall parity holds; see
+        # tests/test_expand.py).
+        if e > 1:
+            l = compact_width(m, e, cfg.compact)
+            _, keep = jax.lax.top_k(fresh.astype(jnp.float32), l)
+            nbrs, safe, fresh = nbrs[keep], safe[keep], fresh[keep]
+            w = safe >> 5
+            bit = jnp.uint32(1) << (safe & 31).astype(jnp.uint32)
+            src = keep // m                                # parent pop slot
+        else:
+            src = jnp.arange(e * m, dtype=jnp.int32) // m
+        visited = visited.at[w].add(jnp.where(fresh, bit, jnp.uint32(0)))
 
-    # tombstoned lanes stay in ``fresh`` (visited-marked, never re-checked)
-    # but are folded into the FEE exit mask: zero segments streamed, never
-    # inserted into the beam, and invisible to the trace (``live``).
-    alive = None if tombstone is None else ~tombstone_lookup(tombstone, safe)
-    live = fresh if alive is None else fresh & alive
+        # tombstoned lanes stay in ``fresh`` (visited-marked, never
+        # re-checked) but are folded into the FEE exit mask: zero segments
+        # streamed, never inserted into the beam, and invisible to the trace
+        # (``live``).
+        alive = (None if tombstone is None
+                 else ~tombstone_lookup(tombstone, safe))
+        live = fresh if alive is None else fresh & alive
 
     threshold = beam_d[-1]
     tiered = cfg.storage == "tiered"
-    if tiered:                # (L, Wc) coarse + (L, Wr) residual tier rows
-        tgt = (vectors[0][safe], vectors[1][safe])
-    else:
-        tgt = vectors[safe]                      # (L, D) f32 / (L, W) packed
-    score, rejected, segs_used = _score(q, tgt, threshold, fee, cfg, dfl_cfg,
-                                        alive)
+    with jax.named_scope("hop.gather"):
+        if tiered:                # (L, Wc) coarse + (L, Wr) residual tier rows
+            tgt = (vectors[0][safe], vectors[1][safe])
+        else:
+            tgt = vectors[safe]                  # (L, D) f32 / (L, W) packed
+    with jax.named_scope("hop.score"):
+        score, rejected, segs_used = _score(q, tgt, threshold, fee, cfg,
+                                            dfl_cfg, alive)
 
     # ---- single top-k beam merge over (ef + L) candidates
-    cand_d = jnp.where(fresh & ~rejected, score, BIG)
-    beam_ids, beam_d, expanded = merge_beam(beam_ids, beam_d, expanded,
-                                            safe, cand_d)
+    with jax.named_scope("hop.merge"):
+        cand_d = jnp.where(fresh & ~rejected, score, BIG)
+        beam_ids, beam_d, expanded = merge_beam(beam_ids, beam_d, expanded,
+                                                safe, cand_d)
 
     trace = dict(
         node=nodes.astype(jnp.int32),
@@ -376,7 +385,8 @@ def _search_batch(vectors, adj, fee, tombstone, queries, entries, *,
     cnt_keys = ("n_eval", "dims", "n_resid") if tiered else ("n_eval", "dims")
 
     def search_one(q, entry):
-        state = _init_state(q, entry, vectors, cfg, n_words, dfl_cfg)
+        with jax.named_scope("search.init"):
+            state = _init_state(q, entry, vectors, cfg, n_words, dfl_cfg)
         counters = None
         if trace:
             def step(s, _):
@@ -501,11 +511,13 @@ def _greedy_level(vecs_l, adj_l, queries, cur, *, metric: str):
         c, _, _ = jax.lax.while_loop(cond, body, (c, d0, jnp.bool_(True)))
         return c
 
-    return jax.vmap(greedy)(queries, cur)
+    with jax.named_scope("descent.level"):
+        return jax.vmap(greedy)(queries, cur)
 
 
 def descend_entry(vectors, graph, queries, metric: str) -> np.ndarray:
-    """Greedy top-down routing through HNSW upper layers -> base entry ids.
+    """Greedy top-down routing through HNSW upper layers -> base entry ids,
+    one ``search.descent`` span (attrs ``level``, ``rows``) per level.
 
     ``vectors`` is either the dense (N, D) f32 array or a callable
     ``ids -> (len(ids), D) f32`` row provider — the latter lets packed-native
@@ -515,14 +527,17 @@ def descend_entry(vectors, graph, queries, metric: str) -> np.ndarray:
     fetch = vectors if callable(vectors) else (lambda ids: vectors[ids])
     entries = np.full(len(queries), graph.entry, np.int64)
     queries = jnp.asarray(queries)
-    for ids, adj in reversed(graph.levels[1:]):
-        # level ids are sorted by construction (graph.build_graph)
-        pos = np.clip(np.searchsorted(ids, entries), 0, len(ids) - 1)
-        cur = np.where(ids[pos] == entries, pos, 0).astype(np.int32)
-        cur = np.asarray(_greedy_level(jnp.asarray(fetch(ids)),
-                                       jnp.asarray(adj, jnp.int32),
-                                       queries, jnp.asarray(cur), metric=metric))
-        entries = ids[cur]
+    for level in range(len(graph.levels) - 1, 0, -1):
+        ids, adj = graph.levels[level]
+        with tracer.span("search.descent", level=level, rows=len(ids)):
+            # level ids are sorted by construction (graph.build_graph)
+            pos = np.clip(np.searchsorted(ids, entries), 0, len(ids) - 1)
+            cur = np.where(ids[pos] == entries, pos, 0).astype(np.int32)
+            cur = np.asarray(_greedy_level(jnp.asarray(fetch(ids)),
+                                           jnp.asarray(adj, jnp.int32),
+                                           queries, jnp.asarray(cur),
+                                           metric=metric))
+            entries = ids[cur]
     return entries.astype(np.int32)
 
 

@@ -38,19 +38,29 @@ from repro.core import graph as gmod
 from repro.core import search as search_mod
 from repro.core.fee import FeeParams
 from repro.index.types import SearchParams, SearchResult
-from repro.obs import default_registry
+from repro.obs import default_registry, tracer
 
 
 def _record_search(res: SearchResult, dim: int, bytes_per_dim: float) -> None:
     """Feed one batch's :class:`SearchResult` counters into the process-wide
-    telemetry registry (``repro.obs.default_registry``): queries served, hops,
-    lanes evaluated, feature dims touched vs touchable (the FEE exit fraction
-    is derivable as ``1 - dims_touched/dims_possible``), residual-tier fetches
-    and approximate payload bytes streamed from the base-vector store."""
+    telemetry registry (``repro.obs.default_registry``): program executions
+    (``search.batches``), queries served, hops, lanes evaluated, feature dims
+    touched vs touchable (the FEE exit fraction is derivable as
+    ``1 - dims_touched/dims_possible``), residual-tier fetches and
+    approximate payload bytes streamed from the base-vector store.
+
+    ``search.hop_slots`` adds ``len(hops) * max(hops)`` per batch: the
+    vmapped while-loop runs every lane until the batch's slowest lane stops,
+    so ``search.hops / search.hop_slots`` is the useful share of the hop
+    iterations the device ran."""
     reg = default_registry()
+    reg.counter("search.batches").inc()
     reg.counter("search.queries").inc(len(res.ids))
     if res.hops is not None:
         reg.counter("search.hops").inc(float(np.sum(res.hops)))
+        if len(res.hops):
+            reg.counter("search.hop_slots").inc(
+                float(len(res.hops) * np.max(res.hops)))
     if res.n_eval is not None:
         reg.counter("search.lanes_evaluated").inc(float(np.sum(res.n_eval)))
     if res.dims is not None:
@@ -148,12 +158,20 @@ def local_searcher(index, params: SearchParams, *, fee=None):
         bpd = 4.0
 
     def run(queries) -> SearchResult:
-        qr = index.transform_queries(np.asarray(queries))
+        # the rotated queries go to the device once, for the descent and
+        # the search program both
+        with tracer.span("search.pca"):
+            qr = jnp.asarray(index.transform_queries(np.asarray(queries)))
         entries = search_mod.descend_entry(rows, index.graph, qr, index.metric)
-        res = SearchResult.from_raw(searcher(jnp.asarray(qr),
-                                             jnp.asarray(entries)))
+        # dispatch returns once the program is enqueued; the wait blocks on
+        # the device and the copy back
+        with tracer.span("search.dispatch"):
+            raw = searcher(qr, jnp.asarray(entries))
+        with tracer.span("search.wait"):
+            res = SearchResult.from_raw(raw)
         res.generation = index.generation
-        _record_search(res, index.dim, bpd)
+        with tracer.span("search.count"):
+            _record_search(res, index.dim, bpd)
         return res
 
     run.lower = searcher.lower   # (rotated queries, entries) -> Lowered

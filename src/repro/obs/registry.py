@@ -32,7 +32,7 @@ from pathlib import Path
 import numpy as np
 
 __all__ = ["Counter", "Gauge", "Histogram", "QuantileSketch", "Registry",
-           "PeriodicExporter", "default_registry"]
+           "PeriodicExporter", "default_registry", "count_compiles"]
 
 
 class QuantileSketch:
@@ -348,6 +348,37 @@ def default_registry() -> Registry:
     streaming mutation, resilience).  Serving metrics use a private registry
     per server — see :class:`repro.serve.Metrics`."""
     return _default
+
+
+# jax.monitoring duration events -> default-registry counters
+_COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compiles",
+    "/jax/core/compile/jaxpr_trace_duration": "jax.traces",
+}
+_compile_listener_lock = threading.Lock()
+_compile_listener = None
+
+
+def count_compiles() -> None:
+    """Count XLA backend compiles and jaxpr traces of this process in the
+    default registry (``jax.backend_compiles``, ``jax.traces``).
+
+    Registers one ``jax.monitoring`` duration listener per process; later
+    calls do nothing.  It runs only when JAX traces or compiles, so a
+    steady serving loop pays nothing for it."""
+    global _compile_listener
+    with _compile_listener_lock:
+        if _compile_listener is not None:
+            return
+        import jax.monitoring
+
+        def listener(event: str, duration_secs: float, **kw) -> None:
+            name = _COMPILE_EVENTS.get(event)
+            if name is not None:
+                _default.counter(name).inc()
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        _compile_listener = listener
 
 
 class PeriodicExporter:

@@ -15,11 +15,25 @@ from any thread.  Design constraints, in order:
   3. **Attribution.**  Spans carry an optional request id (``req``) plus
      free-form attributes; per-request timelines and Chrome-trace exports
      are derived views over the ring.
+  4. **One clock with the device.**  While the tracer is enabled, every
+     ``with tracer.span(name)`` also enters ``jax.profiler.TraceAnnotation``
+     under the same plain name, so a running JAX profiler records the span
+     on the calling thread's host line, on the clock of the device planes.
+     ``add_span`` and ``instant`` are back-dated or zero-length, so they
+     stay in the ring only.  JAX is imported by :meth:`Tracer.enable`.
 
-The serving stages instrumented end-to-end (see ``repro.serve.batcher``)::
+The serving stages instrumented end-to-end (see ``repro.serve.batcher``),
+one back-dated span per request per stage::
 
     queue_wait -> admission -> bucket_pad -> device_exec -> topk_slice
                -> resolve
+
+One live span per batch per stage on the batcher thread, siblings that
+never nest (``SERVE_BATCH_STAGES``; ``search.descent`` once per upper
+level)::
+
+    serve.take -> serve.admit -> serve.pad -> search.pca -> search.descent
+      -> search.dispatch -> search.wait -> search.count -> serve.resolve
 
 plus named spans around generation hot-swap installs (``swap.install``), WAL
 flushes (``wal.flush``) and watchdog restarts (instant events).
@@ -38,11 +52,15 @@ from collections import deque
 from pathlib import Path
 
 __all__ = ["Span", "Tracer", "tracer", "span", "enable_tracing",
-           "disable_tracing", "SERVE_STAGES"]
+           "disable_tracing", "SERVE_STAGES", "SERVE_BATCH_STAGES"]
 
 # canonical request lifecycle stage names, in order (the timeline contract)
 SERVE_STAGES = ("queue_wait", "admission", "bucket_pad", "device_exec",
                 "topk_slice", "resolve")
+# per-batch stage spans of the batcher thread, in order
+SERVE_BATCH_STAGES = ("serve.take", "serve.admit", "serve.pad", "search.pca",
+                      "search.descent", "search.dispatch", "search.wait",
+                      "search.count", "serve.resolve")
 
 
 class Span:
@@ -104,7 +122,8 @@ _NOOP = _NoopSpan()
 class _LiveSpan:
     """Context manager for an in-flight span (enabled path only)."""
 
-    __slots__ = ("_tracer", "name", "req", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "req", "attrs", "_t0", "_depth",
+                 "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, req, attrs):
         self._tracer = tracer
@@ -120,11 +139,14 @@ class _LiveSpan:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._mirror = self._tracer._annotation(self.name)
+        self._mirror.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self._t0
+        self._mirror.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -138,12 +160,15 @@ class Tracer:
     """Span recorder with a bounded ring of completed spans."""
 
     def __init__(self, capacity: int = 65536, enabled: bool = False):
-        self.enabled = enabled
+        self.enabled = False
         self.capacity = capacity
         self.dropped = 0            # spans that fell off the ring tail
         self._ring: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._annotation = None     # jax.profiler.TraceAnnotation, on enable
+        if enabled:
+            self.enable()
 
     # -- recording -----------------------------------------------------------
     def _stack(self) -> list:
@@ -186,6 +211,10 @@ class Tracer:
             with self._lock:
                 self.capacity = capacity
                 self._ring = deque(self._ring, maxlen=capacity)
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self.enabled = True
         return self
 

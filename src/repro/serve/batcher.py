@@ -20,7 +20,10 @@ Tracing: ``resolve_batch`` stamps the stage boundaries of every batch
 request) and, when the process tracer is enabled, emits one span per request
 per stage: ``queue_wait -> admission -> bucket_pad -> device_exec ->
 topk_slice -> resolve``.  The span construction itself is guarded behind
-``tracer.enabled``, so the disabled hot path allocates nothing.
+``tracer.enabled``, so the disabled hot path allocates nothing.  The batch
+itself gets live stage spans (mirrored into the JAX profiler's trace):
+``serve.pad`` in ``run_bucketed`` and ``serve.resolve`` in
+``resolve_batch``, around the searcher's own ``search.*`` spans.
 """
 from __future__ import annotations
 
@@ -40,22 +43,25 @@ def params_for(cfg, ef_bucket: int, expand: int, storage: str) -> SearchParams:
                         or storage in ("packed", "tiered"))
 
 
-def run_bucketed(snapshot, cfg, queries: np.ndarray, ef_bucket: int,
+def run_bucketed(snapshot, cfg, queries, ef_bucket: int,
                  expand: int, storage: str, bucket: int | None = None,
                  timings: dict | None = None):
-    """Run ``queries`` through the (ef_bucket, expand, storage) program at the
-    padded batch bucket; returns ``(ids, dists, generation, service_s)`` with
-    the padding rows already dropped.  ``bucket`` pins the batch bucket (a
-    test replaying one request against the exact program that served it).
+    """Run ``queries`` (an (n, D) array or n rows) through the (ef_bucket,
+    expand, storage) program at the padded batch bucket; returns ``(ids,
+    dists, generation, service_s)`` with the padding rows already dropped.
+    ``bucket`` pins the batch bucket (a test replaying one request against
+    the exact program that served it).
     ``timings`` (optional dict) receives the ``t_exec_ns``/``t_done_ns``
     stage boundaries so the caller can attribute pad vs device time."""
-    n = len(queries)
-    bucket = bucket or cfg.batch_bucket(n)
-    if n < bucket:
-        pad = np.repeat(queries[-1:], bucket - n, axis=0)
-        queries = np.concatenate([queries, pad], axis=0)
-    run = snapshot.searcher("local", params_for(cfg, ef_bucket, expand,
-                                                storage))
+    with tracer.span("serve.pad"):
+        queries = np.stack(queries)
+        n = len(queries)
+        bucket = bucket or cfg.batch_bucket(n)
+        if n < bucket:
+            pad = np.repeat(queries[-1:], bucket - n, axis=0)
+            queries = np.concatenate([queries, pad], axis=0)
+        run = snapshot.searcher("local", params_for(cfg, ef_bucket, expand,
+                                                    storage))
     t0_ns = time.perf_counter_ns()
     res = run(queries)
     t1_ns = time.perf_counter_ns()
@@ -80,63 +86,67 @@ def resolve_batch(snapshot, cfg, serve: list, ef_bucket: int, degraded: bool,
     fault_point("serve.batch_exec", ids=[r.id for r in serve])
     group = serve[0].group(cfg)
     t_pad_ns = time.perf_counter_ns()
-    queries = np.stack([r.query for r in serve])
     bucket = cfg.batch_bucket(len(serve))
     timings = {}
     ids, dists, gen, service_s, res = run_bucketed(
-        snapshot, cfg, queries, ef_bucket, group[1], group[2], bucket=bucket,
-        timings=timings)
+        snapshot, cfg, [r.query for r in serve], ef_bucket, group[1],
+        group[2], bucket=bucket, timings=timings)
     t_exec_ns, t_done_ns = timings["t_exec_ns"], timings["t_done_ns"]
-    if model is not None:
-        model.observe((ef_bucket,) + group[1:], bucket, service_s)
-    n = len(serve)
-    if resid_metrics is not None and res.n_eval is not None:
-        # live search counters (padding rows dropped — they duplicate the
-        # last real query's counters): FEE exit fraction for every storage,
-        # plus tiered per-bucket survivor-fetch accounting
-        n_eval = float(np.asarray(res.n_eval)[:n].sum())
-        dim = getattr(snapshot, "dim", None)
-        if res.dims is not None and dim:
-            resid_metrics.record_batch(
-                n_eval, float(np.asarray(res.dims)[:n].sum()), dim)
-        if res.n_resid is not None:
-            resid_metrics.record_residual(
-                ef_bucket, n_eval, float(np.asarray(res.n_resid)[:n].sum()))
-    # per-request top-k slices first, then response construction (the resolve
-    # stage), so the stage boundaries are real shared timestamps rather than
-    # interleaved per-request work.  ``total_ms`` is stamped when the resolve
-    # stage *ends* — the traced stage durations sum to it exactly — while the
-    # future propagation (done-callbacks, metrics) stays outside both.
-    slices = [(np.asarray(ids[i, : r.k]), np.asarray(dists[i, : r.k]))
-              for i, r in enumerate(serve)]
-    t_slice_ns = time.perf_counter_ns()
-    responses = [Response(
-        id=r.id, status="ok", ids=ids_i, dists=dists_i,
-        generation=gen, ef_served=ef_bucket, batch_bucket=bucket,
-        degraded=degraded and ef_bucket < r.group(cfg)[0],
-        queue_ms=(t_exec_ns / 1e9 - _NS_EPOCH - r.t_submit) * 1e3,
-        service_ms=service_s * 1e3)
-        for (ids_i, dists_i), r in zip(slices, serve)]
-    t_res_ns = time.perf_counter_ns()
-    now = t_res_ns / 1e9 - _NS_EPOCH
-    for resp, r in zip(responses, serve):
-        resp.total_ms = r.elapsed_ms(now)
-        resp.deadline_missed = resp.total_ms > r.deadline_ms
-        r.future.set_result(resp)
-    if tracer.enabled:
-        taken = t_taken_ns if t_taken_ns is not None else t_pad_ns
-        admitted = t_admitted_ns if t_admitted_ns is not None else t_pad_ns
-        for r in serve:
-            sub_ns = int((r.t_submit + _NS_EPOCH) * 1e9)
-            rid = r.id
-            tracer.add_span("queue_wait", sub_ns, taken, req=rid)
-            tracer.add_span("admission", taken, admitted, req=rid, depth=0)
-            tracer.add_span("bucket_pad", admitted, t_exec_ns, req=rid,
-                            bucket=bucket, n=n)
-            tracer.add_span("device_exec", t_exec_ns, t_done_ns, req=rid,
-                            ef=ef_bucket, storage=group[2])
-            tracer.add_span("topk_slice", t_done_ns, t_slice_ns, req=rid)
-            tracer.add_span("resolve", t_slice_ns, t_res_ns, req=rid)
+    with tracer.span("serve.resolve"):
+        if model is not None:
+            model.observe((ef_bucket,) + group[1:], bucket, service_s)
+        n = len(serve)
+        if resid_metrics is not None and res.n_eval is not None:
+            # live search counters (padding rows dropped — they duplicate
+            # the last real query's counters): FEE exit fraction for every
+            # storage, plus tiered per-bucket survivor-fetch accounting
+            n_eval = float(np.asarray(res.n_eval)[:n].sum())
+            dim = getattr(snapshot, "dim", None)
+            if res.dims is not None and dim:
+                resid_metrics.record_batch(
+                    n_eval, float(np.asarray(res.dims)[:n].sum()), dim)
+            if res.n_resid is not None:
+                resid_metrics.record_residual(
+                    ef_bucket, n_eval,
+                    float(np.asarray(res.n_resid)[:n].sum()))
+        # per-request top-k slices first, then response construction (the
+        # resolve stage), so the stage boundaries are real shared timestamps
+        # rather than interleaved per-request work.  ``total_ms`` is stamped
+        # when the resolve stage *ends* — the traced stage durations sum to
+        # it exactly — while the future propagation (done-callbacks,
+        # metrics) stays outside both.
+        slices = [(np.asarray(ids[i, : r.k]), np.asarray(dists[i, : r.k]))
+                  for i, r in enumerate(serve)]
+        t_slice_ns = time.perf_counter_ns()
+        responses = [Response(
+            id=r.id, status="ok", ids=ids_i, dists=dists_i,
+            generation=gen, ef_served=ef_bucket, batch_bucket=bucket,
+            degraded=degraded and ef_bucket < r.group(cfg)[0],
+            queue_ms=(t_exec_ns / 1e9 - _NS_EPOCH - r.t_submit) * 1e3,
+            service_ms=service_s * 1e3)
+            for (ids_i, dists_i), r in zip(slices, serve)]
+        t_res_ns = time.perf_counter_ns()
+        now = t_res_ns / 1e9 - _NS_EPOCH
+        for resp, r in zip(responses, serve):
+            resp.total_ms = r.elapsed_ms(now)
+            resp.deadline_missed = resp.total_ms > r.deadline_ms
+            r.future.set_result(resp)
+        if tracer.enabled:
+            taken = t_taken_ns if t_taken_ns is not None else t_pad_ns
+            admitted = (t_admitted_ns if t_admitted_ns is not None
+                        else t_pad_ns)
+            for r in serve:
+                sub_ns = int((r.t_submit + _NS_EPOCH) * 1e9)
+                rid = r.id
+                tracer.add_span("queue_wait", sub_ns, taken, req=rid)
+                tracer.add_span("admission", taken, admitted, req=rid,
+                                depth=0)
+                tracer.add_span("bucket_pad", admitted, t_exec_ns, req=rid,
+                                bucket=bucket, n=n)
+                tracer.add_span("device_exec", t_exec_ns, t_done_ns, req=rid,
+                                ef=ef_bucket, storage=group[2])
+                tracer.add_span("topk_slice", t_done_ns, t_slice_ns, req=rid)
+                tracer.add_span("resolve", t_slice_ns, t_res_ns, req=rid)
     return service_s
 
 

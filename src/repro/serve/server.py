@@ -35,7 +35,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
-from repro.obs import tracer
+from repro.obs import count_compiles, tracer
 from repro.resilience import InjectedCrash, fault_point
 from repro.serve.admission import AdmissionController, LatencyModel
 from repro.serve.batcher import fail_timeouts, resolve_batch_safe
@@ -77,6 +77,7 @@ class Server:
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "Server":
         t0 = time.perf_counter()
+        count_compiles()
         snap = (self._mutable.freeze() if self._mutable is not None
                 else self._static)
         self.installer.install(snap)
@@ -195,28 +196,32 @@ class Server:
             if self.installer.maybe_install() is not None:
                 snap = self.installer.serving
                 self.history.append((snap.generation, snap))
-            batch = self.queue.take_group(group_of, cfg.batch_max,
-                                          timeout=0.02,
-                                          linger=cfg.max_wait_ms / 1e3)
+            with tracer.span("serve.take") as take:
+                batch = self.queue.take_group(group_of, cfg.batch_max,
+                                              timeout=0.02,
+                                              linger=cfg.max_wait_ms / 1e3)
+                take.set(n=len(batch))
             if not batch:
                 continue
             t_taken_ns = time.perf_counter_ns()
-            if not breaker.allow():
-                # open breaker: shed without any device work — failing fast
-                # beats burning every request's deadline on a broken backend
-                now = time.perf_counter()
-                for r in batch:
-                    if not r.future.done():
-                        r.future.set_result(Response(
-                            id=r.id, status="shed",
-                            queue_ms=r.elapsed_ms(now),
-                            total_ms=r.elapsed_ms(now)))
-                self.metrics.record_event("breaker_shed", len(batch))
-                continue
-            serve, timed_out, ef, degraded = self.admission.plan(
-                batch, len(self.queue))
-            t_admitted_ns = time.perf_counter_ns()
-            fail_timeouts(timed_out)
+            with tracer.span("serve.admit"):
+                if not breaker.allow():
+                    # open breaker: shed without any device work — failing
+                    # fast beats burning every request's deadline on a
+                    # broken backend
+                    now = time.perf_counter()
+                    for r in batch:
+                        if not r.future.done():
+                            r.future.set_result(Response(
+                                id=r.id, status="shed",
+                                queue_ms=r.elapsed_ms(now),
+                                total_ms=r.elapsed_ms(now)))
+                    self.metrics.record_event("breaker_shed", len(batch))
+                    continue
+                serve, timed_out, ef, degraded = self.admission.plan(
+                    batch, len(self.queue))
+                t_admitted_ns = time.perf_counter_ns()
+                fail_timeouts(timed_out)
             if not serve:
                 continue
             try:

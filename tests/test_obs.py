@@ -231,6 +231,61 @@ def test_disabled_tracer_is_allocation_free_singleton():
     assert tr.spans() == []
 
 
+def test_disabled_after_enable_is_still_the_noop_singleton():
+    """Disabling a tracer that was on (and so has the profiler mirror
+    loaded) returns the disabled path to the shared no-op object."""
+    from repro.obs.trace import _NOOP
+
+    tr = Tracer(enabled=True)
+    tr.disable()
+    assert tr.span("serve.pad") is _NOOP
+    assert tr.span("search.descent", level=2, rows=10) is _NOOP
+    with tr.span("serve.resolve"):
+        pass
+    assert tr.spans() == []
+
+
+def test_live_span_is_mirrored_into_the_profiler_trace(tmp_path):
+    """A span entered while the JAX profiler traces lands on a host plane of
+    the written xplane under its exact name (no attributes folded in), on
+    the line of the thread that entered it; the ring holds the same name."""
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(enabled=True)
+
+    def worker():
+        with tr.span("obs.test.worker", req=3, n=32):
+            with jax.profiler.TraceAnnotation("obs.test.worker_marker"):
+                pass
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        with tr.span("obs.test.main", level=1):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    where = {}                      # event name -> {(plane, line index)}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host"):
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    where.setdefault(e.name, set()).add((plane.name, i))
+    assert len(where["obs.test.worker"]) == 1
+    assert where["obs.test.worker"] == where["obs.test.worker_marker"]
+    assert len(where["obs.test.main"]) == 1
+    assert where["obs.test.main"] != where["obs.test.worker"]
+    assert [s.name for s in tr.spans()] == ["obs.test.worker",
+                                            "obs.test.main"]
+
+
 def test_disabled_hot_path_cost_is_negligible():
     """`span()` when disabled must be ~an attribute check — bound the cost
     relative to a bare function call rather than wall-clock (CI noise)."""

@@ -129,3 +129,108 @@ def test_disabled_tracing_serves_identically(unit_db, unit_index):
         resps = [f.result(timeout=60) for f in futs]
     assert all(r.status == "ok" for r in resps)
     assert obs.tracer.spans() == []
+
+
+def test_batch_stage_spans_tile_the_batcher_thread(unit_db, unit_index,
+                                                   traced):
+    """Every served batch yields each stage span once on the batcher thread
+    (``search.descent`` once per upper level); the spans never overlap and
+    cover >= 90 % of the thread's time from the end of ``serve.take`` to the
+    end of ``serve.resolve``.  The per-request timelines still sum to
+    ``total_ms``."""
+    cfg = ServeConfig(ef_buckets=(32,), batch_buckets=(8,), k_max=K,
+                      slo_ms=5000.0)
+    with Server(unit_index, cfg) as srv:
+        futs = [srv.submit(unit_db.queries[i], k=K, ef=32, deadline_ms=5000.0)
+                for i in range(16)]
+        resps = [f.result(timeout=60) for f in futs]
+    assert all(r.status == "ok" for r in resps)
+    spans = traced.spans()
+    takes = [s for s in spans if s.name == "serve.take" and s.attrs["n"]]
+    assert takes and sum(s.attrs["n"] for s in takes) == 16
+    (tid,) = {s.tid for s in takes}
+    mine = sorted((s for s in spans if s.tid == tid
+                   and s.name in obs.SERVE_BATCH_STAGES),
+                  key=lambda s: s.t0_ns)
+    levels = len(unit_index.graph.levels) - 1
+    assert levels >= 1
+    for take in takes:
+        resolve = next(s for s in mine if s.name == "serve.resolve"
+                       and s.t0_ns >= take.t1_ns)
+        batch = [s for s in mine if take.t0_ns <= s.t0_ns <= resolve.t0_ns]
+        names = [s.name for s in batch]
+        assert names == ["serve.take", "serve.admit", "serve.pad",
+                         "search.pca"] + ["search.descent"] * levels + [
+                         "search.dispatch", "search.wait", "search.count",
+                         "serve.resolve"], names
+        assert [s.attrs["level"] for s in batch
+                if s.name == "search.descent"] == list(range(levels, 0, -1))
+        assert all(s.depth == 0 for s in batch)
+        for a, b in zip(batch, batch[1:]):
+            assert a.t1_ns <= b.t0_ns, (a, b)
+        covered = sum(s.dur_ns for s in batch[1:])
+        assert covered >= 0.9 * (resolve.t1_ns - take.t1_ns)
+    for r in resps:
+        tl = traced.request_timeline(r.id)
+        assert sum(row["dur_ms"] for row in tl
+                   if row["stage"] in obs.SERVE_STAGES) == \
+            pytest.approx(r.total_ms, rel=0.05)
+
+
+def test_search_counters_hop_slots_batches_and_compiles(unit_db, unit_index):
+    """``search.hop_slots`` adds len(hops) * max(hops) per batch and
+    ``search.batches`` one per program execution; the compile and trace
+    counters rise for a new batch shape and stay for a repeated one."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.index import SearchParams
+
+    obs.count_compiles()
+    obs.count_compiles()            # idempotent: one listener per process
+    reg = obs.default_registry()
+    names = ("search.batches", "search.hops", "search.hop_slots",
+             "jax.backend_compiles", "jax.traces")
+
+    def read():
+        return {n: reg.counter(n).value for n in names}
+
+    # no persistent cache: a new shape must compile, not load
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        run = unit_index.searcher("local", SearchParams(ef=32, k=K))
+        run(unit_db.queries[:5])
+        c0 = read()
+        results = [run(unit_db.queries[i:i + 5]) for i in (0, 5, 10)]
+        c1 = read()
+        run(unit_db.queries[:7])
+        c2 = read()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        cc.reset_cache()
+    assert c1["search.batches"] - c0["search.batches"] == 3
+    assert c1["search.hops"] - c0["search.hops"] == \
+        sum(int(r.hops.sum()) for r in results)
+    assert c1["search.hop_slots"] - c0["search.hop_slots"] == \
+        sum(len(r.hops) * int(r.hops.max()) for r in results)
+    assert c1["search.hops"] - c0["search.hops"] <= \
+        c1["search.hop_slots"] - c0["search.hop_slots"]
+    assert c1["jax.backend_compiles"] == c0["jax.backend_compiles"]
+    assert c1["jax.traces"] == c0["jax.traces"]
+    assert c2["jax.backend_compiles"] > c1["jax.backend_compiles"]
+    assert c2["jax.traces"] > c1["jax.traces"]
+    assert c2["search.batches"] - c1["search.batches"] == 1
+
+
+def test_server_start_registers_the_compile_counters(unit_index):
+    """A started server counts compiles into the default registry, which
+    ``launch/serve.py --metrics-out`` exports."""
+    cfg = ServeConfig(ef_buckets=(32,), batch_buckets=(3,), k_max=K,
+                      slo_ms=5000.0)
+    with Server(unit_index, cfg):
+        pass
+    snap = obs.default_registry().snapshot()
+    assert snap["jax.backend_compiles"]["value"] > 0
+    assert snap["jax.traces"]["value"] > 0

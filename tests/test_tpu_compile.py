@@ -8,11 +8,19 @@ Interpret mode (every other kernel test) cannot see what the chip's compiler
 refuses: block shapes off the (8, 128) tiling, unsupported in-kernel layout
 casts, VMEM overflow.  These tests can, with no chip attached.
 
+The compiled search program also keeps the stage scopes a device trace is
+attributed by (``hop.*``, ``search.init``, ``descent.level``) and the FEE
+kernel instruction name the benchmark's trace reduction matches.
+
 The topology is described inside a module fixture, never while a module is
 imported: only one process at a time may load the TPU library, and it keeps
 it until it exits.  Keep every such test in this one file.
 """
+import importlib.util
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +40,10 @@ LANES = search_mod.compact_width(16, 4)    # frontier lanes per hop at M=16
 # fields that straddle 32-bit words (21, 18 and 14 bits)
 LAYOUTS = {128: [(21, 6, 48), (14, 5, 80)],
            960: [(18, 6, 320), (14, 5, 320), (12, 4, 320)]}
+# jax.named_scope stages of one hop and of the search's set-up
+SEARCH_SCOPES = ("search.init", "hop.frontier", "hop.gather", "hop.score",
+                 "hop.merge")
+TRACE_REDUCE = Path(__file__).resolve().parents[1] / "bench" / "trace_reduce.py"
 
 
 @pytest.fixture(scope="module")
@@ -119,11 +131,68 @@ def test_dfloat_unpack_compiles_for_tpu(one_chip, d):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compile_search(one_chip, storage):
+    """The whole jitted local search (vmap over queries of the hop
+    while_loop) with ``fee_backend="auto"`` dispatching to the kernels,
+    compiled for one chip; returns the compiled HLO text."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kops, "_on_tpu", lambda: True)
+        return _lower_search(one_chip, storage).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def search_hlo(one_chip):
+    """``storage -> compiled HLO text``, each storage compiled once."""
+    cache = {}
+
+    def get(storage):
+        if storage not in cache:
+            cache[storage] = _compile_search(one_chip, storage)
+        return cache[storage]
+
+    return get
+
+
+def _scoped(hlo: str, scope: str) -> bool:
+    """Some instruction's ``op_name`` holds ``scope`` as a path element
+    (``jit(f)/vmap(hop.score)/...`` under vmap)."""
+    pattern = r'op_name="[^"]*[/(]' + re.escape(scope) + r'[/)]'
+    return re.search(pattern, hlo) is not None
+
+
 @pytest.mark.parametrize("storage", ["f32", "packed", "tiered"])
-def test_search_program_compiles_for_tpu(one_chip, monkeypatch, storage):
+def test_search_program_compiles_for_tpu(search_hlo, storage):
     """The whole jitted local search (vmap over queries of the hop
     while_loop) with ``fee_backend="auto"`` dispatching to the kernels."""
-    monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+    assert "tpu_custom_call" in search_hlo(storage)
+
+
+@pytest.mark.parametrize("storage", ["packed", "tiered"])
+def test_search_program_carries_stage_scopes(search_hlo, storage):
+    """The compiled program's metadata names each hop stage, and its FEE
+    kernel keeps the instruction name the trace reduction matches."""
+    spec = importlib.util.spec_from_file_location("_bench_trace_reduce",
+                                                  TRACE_REDUCE)
+    trace_reduce = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = trace_reduce      # its dataclass looks it up
+    spec.loader.exec_module(trace_reduce)
+    hlo = search_hlo(storage)
+    for scope in SEARCH_SCOPES:
+        assert _scoped(hlo, scope), scope
+    assert any(trace_reduce.FEE_KERNEL.search(line.strip())
+               for line in hlo.splitlines())
+
+
+def test_descent_level_carries_its_scope(one_chip):
+    n, d, m = 257, 128, 16
+    compiled = search_mod._greedy_level.lower(
+        _sds(one_chip, (n, d), jnp.float32), _sds(one_chip, (n, m), jnp.int32),
+        _sds(one_chip, (QUERIES, d), jnp.float32),
+        _sds(one_chip, (QUERIES,), jnp.int32), metric="l2").compile()
+    assert _scoped(compiled.as_text(), "descent.level")
+
+
+def _lower_search(one_chip, storage):
     n, d, m = 4099, 128, 16
     cfg, tiers = _dfloat(d)
     if storage == "f32":
@@ -136,12 +205,11 @@ def test_search_program_compiles_for_tpu(one_chip, monkeypatch, storage):
                         for c in tiers)
         dcfg = tiers
     seg_vec = _sds(one_chip, (d // SEG,), jnp.float32)
-    compiled = search_mod._search_batch.lower(
+    return search_mod._search_batch.lower(
         vectors, _sds(one_chip, (n, m), jnp.int32),
         FeeParams(seg_vec, seg_vec, seg_vec), None,
         _sds(one_chip, (QUERIES, d), jnp.float32),
         _sds(one_chip, (QUERIES,), jnp.int32),
         cfg=search_mod.SearchConfig(ef=64, k=10, seg=SEG, use_fee=True,
                                     storage=storage),
-        trace=False, dfl_cfg=dcfg).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        trace=False, dfl_cfg=dcfg)
