@@ -1,15 +1,17 @@
 """Pallas TPU kernels: FEE-sPCA early-exit distance (the VPE datapath, Fig. 10c/f).
 
 TPU adaptation of the paper's per-burst early exit: candidates are tiled
-(TILE_C per grid row) and the feature axis is streamed through VMEM in
-``seg``-wide blocks (one block = the TPU analogue of one DRAM access group).
-After each block the estimated full distance
+(TILE_C per grid step) and the feature axis is walked in ``seg``-wide blocks
+(one block = the TPU analogue of one DRAM access group).  After each block
+the estimated full distance
 
     est = alpha_s * acc / beta_s - margin_s
 
 is compared against the beam threshold; lanes that exit stop accumulating,
 and once an entire candidate tile has exited the remaining feature blocks'
-*compute* is skipped (`pl.when`).
+*compute* is skipped (`pl.when`).  Whether the tile is still live is asked
+once every ``GATE_BLOCKS`` blocks, so a tile that dies inside a group
+finishes that group with every lane masked.
 
 The kernels are feature-major: candidates fill the 128 lanes and features
 (or packed words) run down the sublanes, so a ``seg``-feature block is a
@@ -21,22 +23,28 @@ Three variants share the accumulate/exit logic:
 
   * ``fee_distance_pallas``        — f32 features, automatic block pipelining
     (exited tiles skip compute, but the BlockSpec pipeline still streams
-    their remaining feature blocks from HBM);
+    their whole feature column from HBM);
   * ``fee_distance_skipdma_pallas``— f32 features kept in HBM (`pl.ANY`); each
-    feature block is fetched with a manual ``make_async_copy`` gated on the
-    tile-exit flag, so exited tiles skip the HBM traffic itself — the paper's
-    actual win (the DIMM stops issuing bursts on exit);
+    feature block is fetched with a manual ``make_async_copy`` inside the
+    tile-exit gate, so exited tiles skip the HBM traffic of every later
+    group — the paper's actual win (the DIMM stops issuing bursts on exit);
   * ``fee_distance_packed_pallas`` — the Dfloat process module fused into the
     VPE datapath (Fig. 10d->10c): candidates arrive as the packed uint32
     bitstream and are decoded in VMEM with static barrel-shifter offsets, so
     only packed bytes ever cross HBM.  ``skip_dma=True`` additionally keeps
-    the bitstream in HBM and manually DMAs only the word range of each live
-    feature block.
+    the bitstream in HBM and manually DMAs only the word range of each
+    feature block of a live group.
 
-Grid: (Q, C // TILE_C, S).  The leading query axis is what ``jax.vmap`` over
-the single-query wrappers turns into (the search loop vmaps them over its
-query batch); the segment axis is sequential ("arbitrary") so the
-accumulator scratch persists across feature blocks of one candidate tile.
+The tiered kernel (``fee_distance_tiered_pallas``) runs the packed datapath
+over a resident coarse tier and fetches the residual tier of the tiles that
+are live at the tier boundary, all of it at once.
+
+Grid: (Q, C // TILE_C), one step per (query, candidate tile).  The leading
+query axis is what ``jax.vmap`` over the single-query wrappers turns into
+(the search loop vmaps them over its query batch).  Inside a step the FEE
+blocks run as a statically unrolled loop — each block's decode offsets are
+compile-time constants — so the per-lane accumulators live in scratch for
+one step only, and both grid axes are "parallel".
 """
 from __future__ import annotations
 
@@ -52,19 +60,15 @@ from repro.kernels.dfloat_unpack import decode_rows
 
 BIG = 3.0e38
 SUBLANES = 8              # rows of one (8, 128) 32-bit VMEM tile
+# FEE blocks per tile-exit check.  The check reduces the tile's lanes to one
+# scalar branch that every later block waits on (≈ 0.3 µs a check on a v5e),
+# so it is made once per group of blocks; exited lanes are masked anyway
+GATE_BLOCKS = 8
 
 
 # ---------------------------------------------------------------------------
 # shared pieces
 # ---------------------------------------------------------------------------
-
-
-def _init_scratch(s, acc, alive, nseg):
-    @pl.when(s == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        alive[:] = jnp.ones_like(alive)
-        nseg[:] = jnp.zeros_like(nseg)
 
 
 def _part_distance(x, q, metric: str):
@@ -74,28 +78,60 @@ def _part_distance(x, q, metric: str):
     return -(x * q).sum(axis=0, keepdims=True)
 
 
-def _accumulate_exit(x, k, q_ref, thr, alpha_ref, beta_ref, margin_ref,
-                     acc, alive, nseg, *, metric: str, last_valid_seg: int):
-    """Score feature block ``k`` (x (seg, TILE_C)) into the live lanes;
-    ``thr`` is this query's beam threshold."""
-    part = _part_distance(x, q_ref[:, :], metric)
-    live = alive[:] > 0
-    acc[:] = acc[:] + jnp.where(live, part, 0.0)
-    nseg[:] = nseg[:] + jnp.where(live, 1, 0)
-    est = alpha_ref[k] * acc[:] / beta_ref[k] - margin_ref[k]
-    # exits only before the last segment (paper Fig. 6: at the last access
-    # the full distance is available anyway)
-    exit_now = live & (est >= thr) & (k < last_valid_seg)
-    alive[:] = jnp.where(exit_now, 0, alive[:])
+def _tile_alive(alive):
+    return alive[:].max() > 0
 
 
-def _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg,
-                  n_segs: int):
-    @pl.when(s == n_segs - 1)
-    def _emit():
-        dist_ref[:, :] = acc[:]
-        rej_ref[:, :] = jnp.where(alive[:] > 0, 0, 1).astype(jnp.int32)
-        segs_ref[:, :] = nseg[:]
+def _scorer(q_ref, thr_ref, alpha_ref, beta_ref, margin_ref, acc, alive,
+            nseg, *, metric: str, seg: int, n_segs: int):
+    """Reset the tile's per-lane state and return ``score(blocks)``.
+
+    ``blocks`` is a list of ``(k, load)``: FEE block ``k`` whose (seg,
+    TILE_C) features ``load()`` gives.  They are scored into the live lanes
+    in groups of ``GATE_BLOCKS``; each group runs, with any fetch its loads
+    issue, only while some lane of the tile is live, and carries the lane
+    state in registers from block to block.  ``before(group)``, where given,
+    runs ahead of each group's gate.
+    """
+    thr = thr_ref[pl.program_id(0)]
+    acc[:] = jnp.zeros_like(acc)
+    alive[:] = jnp.ones_like(alive)
+    nseg[:] = jnp.zeros_like(nseg)
+
+    def run(group):
+        a, live, n = acc[:], alive[:] > 0, nseg[:]
+        for k, load in group:
+            part = _part_distance(load(), q_ref[pl.ds(k * seg, seg), :], metric)
+            a = a + jnp.where(live, part, 0.0)
+            n = n + jnp.where(live, 1, 0)
+            # exits only before the last segment (paper Fig. 6: at the last
+            # access the full distance is available anyway)
+            if k < n_segs - 1:
+                est = alpha_ref[k] * a / beta_ref[k] - margin_ref[k]
+                live = live & ~(est >= thr)
+        acc[:] = a
+        alive[:] = live.astype(jnp.int32)
+        nseg[:] = n
+
+    def score(blocks, before=None):
+        for g in range(0, len(blocks), GATE_BLOCKS):
+            group = blocks[g : g + GATE_BLOCKS]
+            if before is not None:
+                before(group)
+            pl.when(_tile_alive(alive))(functools.partial(run, group))
+
+    return score
+
+
+def _emit_outputs(dist_ref, rej_ref, segs_ref, acc, alive, nseg):
+    dist_ref[:, :] = acc[:]
+    rej_ref[:, :] = jnp.where(alive[:] > 0, 0, 1).astype(jnp.int32)
+    segs_ref[:, :] = nseg[:]
+
+
+def _lanes(tile_c: int):
+    """The lane window of this step's candidate tile."""
+    return pl.ds(pl.multiple_of(pl.program_id(1) * tile_c, tile_c), tile_c)
 
 
 def _word_span(w0: int, w1: int, n_words: int) -> tuple[int, int]:
@@ -124,13 +160,13 @@ def _fee_call(kern, q, xs, x_specs, thr, alpha, beta, margin, *, seg: int,
     n_segs = d // seg
     assert n_segs * seg == d, (d, seg)
     cp = xs[0].shape[-1]
-    lane_row = pl.BlockSpec((None, 1, tile_c), lambda b, i, s: (b, 0, i))
+    lane_row = pl.BlockSpec((None, 1, tile_c), lambda b, i: (b, 0, i))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     dist, rej, segs = pl.pallas_call(
-        functools.partial(kern, n_segs=n_segs, last_valid_seg=n_segs - 1),
-        grid=(nq, cp // tile_c, n_segs),
+        functools.partial(kern, seg=seg, n_segs=n_segs),
+        grid=(nq, cp // tile_c),
         in_specs=[
-            pl.BlockSpec((None, seg, 1), lambda b, i, s: (b, s, 0)),  # q col
+            pl.BlockSpec((None, d, 1), lambda b, i: (b, 0, 0)),  # q column
             *x_specs,
             smem, smem, smem, smem,             # threshold, alpha, beta, margin
         ],
@@ -147,7 +183,7 @@ def _fee_call(kern, q, xs, x_specs, thr, alpha, beta, margin, *, seg: int,
             *scratch,
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
     )(q[:, :, None], *xs, thr.astype(jnp.float32), alpha.astype(jnp.float32),
@@ -195,43 +231,32 @@ def _one_query(batched_fn, n_batched: int):
 
 def _kernel(q_ref, x_ref, thr_ref, alpha_ref, beta_ref, margin_ref,
             dist_ref, rej_ref, segs_ref, acc, alive, nseg,
-            *, metric: str, n_segs: int, last_valid_seg: int):
-    b, s = pl.program_id(0), pl.program_id(2)
-    thr = thr_ref[b]
-    _init_scratch(s, acc, alive, nseg)
-
-    @pl.when(alive[:].max() > 0)
-    def _compute():
-        _accumulate_exit(x_ref[:, :], s, q_ref, thr, alpha_ref, beta_ref,
-                         margin_ref, acc, alive, nseg, metric=metric,
-                         last_valid_seg=last_valid_seg)
-
-    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+            *, metric: str, seg: int, n_segs: int):
+    score = _scorer(q_ref, thr_ref, alpha_ref, beta_ref, margin_ref, acc,
+                    alive, nseg, metric=metric, seg=seg, n_segs=n_segs)
+    score([(k, lambda k=k: x_ref[pl.ds(k * seg, seg), :])
+           for k in range(n_segs)])
+    _emit_outputs(dist_ref, rej_ref, segs_ref, acc, alive, nseg)
 
 
 def _skipdma_kernel(q_ref, x_hbm, thr_ref, alpha_ref, beta_ref, margin_ref,
                     dist_ref, rej_ref, segs_ref, acc, alive, nseg, buf, sem,
-                    *, metric: str, n_segs: int, last_valid_seg: int,
-                    seg: int, tile_c: int):
-    b, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    thr = thr_ref[b]
-    _init_scratch(s, acc, alive, nseg)
+                    *, metric: str, seg: int, n_segs: int):
+    b, lanes = pl.program_id(0), _lanes(buf.shape[1])
+    score = _scorer(q_ref, thr_ref, alpha_ref, beta_ref, margin_ref, acc,
+                    alive, nseg, metric=metric, seg=seg, n_segs=n_segs)
 
-    @pl.when(alive[:].max() > 0)
-    def _fetch_compute():
+    def fetch(k):
         # the burst stream for this feature block is issued only while the
         # tile is live — this is the skip_dma contract
-        dma = pltpu.make_async_copy(
-            x_hbm.at[b, pl.ds(pl.multiple_of(s * seg, seg), seg),
-                     pl.ds(pl.multiple_of(i * tile_c, tile_c), tile_c)],
-            buf, sem)
+        dma = pltpu.make_async_copy(x_hbm.at[b, pl.ds(k * seg, seg), lanes],
+                                    buf, sem)
         dma.start()
         dma.wait()
-        _accumulate_exit(buf[:, :], s, q_ref, thr, alpha_ref, beta_ref,
-                         margin_ref, acc, alive, nseg, metric=metric,
-                         last_valid_seg=last_valid_seg)
+        return buf[:, :]
 
-    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+    score([(k, functools.partial(fetch, k)) for k in range(n_segs)])
+    _emit_outputs(dist_ref, rej_ref, segs_ref, acc, alive, nseg)
 
 
 @functools.lru_cache(maxsize=None)
@@ -240,15 +265,14 @@ def _f32_fn(seg: int, metric: str, tile_c: int, interpret: bool,
     def batched(q, x, thr, alpha, beta, margin):
         xt = _feature_major(x, tile_c)                      # (Q, D, Cp)
         if skip_dma:
-            kern = functools.partial(_skipdma_kernel, metric=metric, seg=seg,
-                                     tile_c=tile_c)
+            kern = functools.partial(_skipdma_kernel, metric=metric)
             x_spec = pl.BlockSpec(memory_space=pl.ANY)
             scratch = [pltpu.VMEM((seg, tile_c), jnp.float32),  # landing buf
                        pltpu.SemaphoreType.DMA]
         else:
             kern = functools.partial(_kernel, metric=metric)
-            x_spec = pl.BlockSpec((None, seg, tile_c),
-                                  lambda b, i, s: (b, s, i))
+            x_spec = pl.BlockSpec((None, xt.shape[1], tile_c),
+                                  lambda b, i: (b, 0, i))
             scratch = []
         return _fee_call(kern, q, [xt], [x_spec], thr, alpha, beta, margin,
                          seg=seg, tile_c=tile_c, scratch=scratch,
@@ -276,9 +300,9 @@ def fee_distance_skipdma_pallas(q, x, threshold, alpha, beta, margin, *,
                                 seg: int, metric: str = "l2", tile_c: int = 128,
                                 interpret: bool = True):
     """Same contract as :func:`fee_distance_pallas`, but ``x`` stays in HBM and
-    feature blocks are fetched with manual async copies gated on the
-    tile-exit flag: a fully-exited tile stops issuing DMAs, so the remaining
-    bursts are never read."""
+    feature blocks are fetched with manual async copies inside the tile-exit
+    gate: a fully-exited tile issues no DMA from the next group of
+    ``GATE_BLOCKS`` blocks on, so the remaining bursts are never read."""
     c = x.shape[0]
     fn = _f32_fn(seg, metric, tile_c, interpret, True)
     return tuple(o[:c] for o in fn(q, x, threshold, alpha, beta, margin))
@@ -310,100 +334,110 @@ def _block_positions(cfg: dfl.DfloatConfig, seg: int):
     return blocks, w_words
 
 
-def _fetch_decode(src_hbm, b, i, positions, w0: int, w1: int, buf, sem,
+def _decoded(src, positions, w0: int, dec):
+    """Decode one block's fields from ``src`` (row ``wi - w0`` holds word
+    ``wi``) into ``dec`` and return them."""
+    decode_rows(src, positions, w0, dec)
+    return dec[:, :]
+
+
+def _fetch_decode(src_hbm, b, lanes, positions, w0: int, w1: int, buf, sem,
                   dec):
-    """Gated manual DMA of one block's word span of (query ``b``, tile
-    ``i``) from HBM, then decode it into ``dec``."""
-    tile_c = buf.shape[1]
-    dma = pltpu.make_async_copy(
-        src_hbm.at[b, pl.ds(w0, w1 - w0),
-                   pl.ds(pl.multiple_of(i * tile_c, tile_c), tile_c)],
-        buf.at[pl.ds(0, w1 - w0), :], sem)
+    """Manual DMA of one block's word span of (query ``b``, tile ``lanes``)
+    from HBM, then decode it into ``dec``."""
+    dma = pltpu.make_async_copy(src_hbm.at[b, pl.ds(w0, w1 - w0), lanes],
+                                buf.at[pl.ds(0, w1 - w0), :], sem)
     dma.start()
     dma.wait()
-    decode_rows(buf, positions, w0, dec)
+    return _decoded(buf, positions, w0, dec)
 
 
 def _packed_kernel(q_ref, xp_ref, thr_ref, alpha_ref, beta_ref, margin_ref,
                    dist_ref, rej_ref, segs_ref, acc, alive, nseg, dec,
-                   *, metric: str, n_segs: int, last_valid_seg: int, blocks):
-    b, s = pl.program_id(0), pl.program_id(2)
-    thr = thr_ref[b]
-    _init_scratch(s, acc, alive, nseg)
-    tile_alive = alive[:].max() > 0
-
-    # the decode offsets of block k are compile-time constants, so the segment
-    # loop is unrolled into one `pl.when` branch per block
-    for k, (positions, _w0, _w1) in enumerate(blocks):
-        @pl.when(tile_alive & (s == k))
-        def _compute(k=k, positions=positions):
-            decode_rows(xp_ref, positions, 0, dec)
-            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
-                             beta_ref, margin_ref, acc, alive, nseg,
-                             metric=metric, last_valid_seg=last_valid_seg)
-
-    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+                   *, metric: str, seg: int, n_segs: int, blocks):
+    score = _scorer(q_ref, thr_ref, alpha_ref, beta_ref, margin_ref, acc,
+                    alive, nseg, metric=metric, seg=seg, n_segs=n_segs)
+    score([(k, functools.partial(_decoded, xp_ref, positions, 0, dec))
+           for k, (positions, _w0, _w1) in enumerate(blocks)])
+    _emit_outputs(dist_ref, rej_ref, segs_ref, acc, alive, nseg)
 
 
 def _packed_skipdma_kernel(q_ref, xp_hbm, thr_ref, alpha_ref, beta_ref,
                            margin_ref, dist_ref, rej_ref, segs_ref,
                            acc, alive, nseg, dec, buf, sem,
-                           *, metric: str, n_segs: int, last_valid_seg: int,
-                           blocks):
-    b, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    thr = thr_ref[b]
-    _init_scratch(s, acc, alive, nseg)
-    tile_alive = alive[:].max() > 0
+                           *, metric: str, seg: int, n_segs: int, blocks):
+    b, lanes = pl.program_id(0), _lanes(buf.shape[1])
+    score = _scorer(q_ref, thr_ref, alpha_ref, beta_ref, margin_ref, acc,
+                    alive, nseg, metric=metric, seg=seg, n_segs=n_segs)
+    score([(k, functools.partial(_fetch_decode, xp_hbm, b, lanes, positions,
+                                 w0, w1, buf, sem, dec))
+           for k, (positions, w0, w1) in enumerate(blocks)])
+    _emit_outputs(dist_ref, rej_ref, segs_ref, acc, alive, nseg)
 
-    for k, (positions, w0, w1) in enumerate(blocks):
-        @pl.when(tile_alive & (s == k))
-        def _fetch_compute(k=k, positions=positions, w0=w0, w1=w1):
-            _fetch_decode(xp_hbm, b, i, positions, w0, w1, buf, sem, dec)
-            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
-                             beta_ref, margin_ref, acc, alive, nseg,
-                             metric=metric, last_valid_seg=last_valid_seg)
 
-    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+def _resid_copies(blocks):
+    """Split the residual words the blocks read into disjoint copies, in
+    block order: copy ``c`` covers words ``[w0, w1)`` that no earlier block
+    reads.  Returns the copies and, per block, the copy it is the first to
+    need (``None`` when earlier copies already hold all of its words)."""
+    copies, first_need, end = [], [], 0
+    for _, w0, w1 in blocks:
+        if w1 > end:
+            first_need.append(len(copies))
+            copies.append((max(w0, end), w1))
+            end = w1
+        else:
+            first_need.append(None)
+    return tuple(copies), tuple(first_need)
 
 
 def _tiered_kernel(q_ref, xc_ref, xr_hbm, thr_ref, alpha_ref, beta_ref,
                    margin_ref, dist_ref, rej_ref, segs_ref,
                    acc, alive, nseg, dec, buf, sem,
-                   *, metric: str, n_segs: int, last_valid_seg: int,
-                   c_blocks, r_blocks):
+                   *, metric: str, seg: int, n_segs: int, c_blocks, r_blocks):
     """Two-tier fused decode+FEE: resident coarse blocks + gated residual DMA.
 
     Blocks ``k < len(c_blocks)`` decode from the VMEM-resident coarse-tier
-    tile (the hot prefix that makes the exit decision); blocks beyond the
-    boundary fetch their word span from the *residual* bitstream in HBM with
-    a ``make_async_copy`` gated on the tile-exit flag — a tile whose lanes all
-    exited inside the coarse tier never issues a residual fetch, so cold-tier
-    traffic moves only for survivors.
+    tile (the hot prefix that makes the exit decision).  At the tier boundary
+    a tile that still has a live lane starts every residual copy at once
+    (disjoint word spans, one semaphore each, landing at their own rows of
+    ``buf``); each residual block then waits only for the copy that brings
+    its last words, so the fetch overlaps the decode of the blocks before
+    it.  A tile whose lanes all exited inside the coarse tier starts no
+    copy; one that dies inside the residual tier stops decoding but still
+    waits for the copies it started.
     """
-    b, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    thr = thr_ref[b]
-    _init_scratch(s, acc, alive, nseg)
-    tile_alive = alive[:].max() > 0
+    b, lanes = pl.program_id(0), _lanes(buf.shape[1])
+    score = _scorer(q_ref, thr_ref, alpha_ref, beta_ref, margin_ref, acc,
+                    alive, nseg, metric=metric, seg=seg, n_segs=n_segs)
+    score([(k, functools.partial(_decoded, xc_ref, positions, 0, dec))
+           for k, (positions, _w0, _w1) in enumerate(c_blocks)])
+
+    spans, first_need = _resid_copies(r_blocks)
+    copies = [pltpu.make_async_copy(xr_hbm.at[b, pl.ds(w0, w1 - w0), lanes],
+                                    buf.at[pl.ds(w0, w1 - w0), :], sem.at[c])
+              for c, (w0, w1) in enumerate(spans)]
+    fetch = _tile_alive(alive)
+
+    @pl.when(fetch)
+    def _start():
+        for dma in copies:
+            dma.start()
+
+    def wait(group):
+        # a started copy is waited for whether or not the tile is still live
+        need = [copies[c] for k, _ in group
+                if (c := first_need[k - n_coarse]) is not None]
+        if need:
+            @pl.when(fetch)
+            def _wait():
+                for dma in need:
+                    dma.wait()
+
     n_coarse = len(c_blocks)
-
-    for k, (positions, _w0, _w1) in enumerate(c_blocks):
-        @pl.when(tile_alive & (s == k))
-        def _compute(k=k, positions=positions):
-            decode_rows(xc_ref, positions, 0, dec)
-            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
-                             beta_ref, margin_ref, acc, alive, nseg,
-                             metric=metric, last_valid_seg=last_valid_seg)
-
-    for j, (positions, w0, w1) in enumerate(r_blocks):
-        k = n_coarse + j
-        @pl.when(tile_alive & (s == k))
-        def _fetch_compute(k=k, positions=positions, w0=w0, w1=w1):
-            _fetch_decode(xr_hbm, b, i, positions, w0, w1, buf, sem, dec)
-            _accumulate_exit(dec[:, :], k, q_ref, thr, alpha_ref,
-                             beta_ref, margin_ref, acc, alive, nseg,
-                             metric=metric, last_valid_seg=last_valid_seg)
-
-    _emit_outputs(s, dist_ref, rej_ref, segs_ref, acc, alive, nseg, n_segs)
+    score([(n_coarse + j, functools.partial(_decoded, buf, positions, 0, dec))
+           for j, (positions, _w0, _w1) in enumerate(r_blocks)], before=wait)
+    _emit_outputs(dist_ref, rej_ref, segs_ref, acc, alive, nseg)
 
 
 def _landing_buf(blocks, tile_c: int):
@@ -427,7 +461,7 @@ def _packed_fn(cfg: dfl.DfloatConfig, seg: int, metric: str, tile_c: int,
                        pltpu.SemaphoreType.DMA]
         else:
             kern = functools.partial(_packed_kernel, **common)
-            xp_spec = pl.BlockSpec((None, w, tile_c), lambda b, i, s: (b, 0, i))
+            xp_spec = pl.BlockSpec((None, w, tile_c), lambda b, i: (b, 0, i))
             scratch = [dec]
         return _fee_call(kern, q, [_feature_major(xp, tile_c)], [xp_spec],
                          thr, alpha, beta, margin, seg=seg, tile_c=tile_c,
@@ -440,17 +474,19 @@ def _tiered_fn(coarse_cfg: dfl.DfloatConfig, resid_cfg: dfl.DfloatConfig,
                seg: int, metric: str, tile_c: int, interpret: bool):
     c_blocks, wc = _block_positions(coarse_cfg, seg)
     r_blocks, wr = _block_positions(resid_cfg, seg)
+    n_copies = len(_resid_copies(r_blocks)[0])
     kern = functools.partial(_tiered_kernel, metric=metric,
                              c_blocks=tuple(c_blocks), r_blocks=tuple(r_blocks))
 
     def batched(q, xc, xr, thr, alpha, beta, margin):
         assert xc.shape[2] == wc and xr.shape[2] == wr, (xc.shape, xr.shape)
         x_specs = [
-            pl.BlockSpec((None, wc, tile_c), lambda b, i, s: (b, 0, i)),  # coarse
+            pl.BlockSpec((None, wc, tile_c), lambda b, i: (b, 0, i)),  # coarse
             pl.BlockSpec(memory_space=pl.ANY),                  # resid (HBM)
         ]
         scratch = [pltpu.VMEM((seg, tile_c), jnp.float32),         # decoded
-                   _landing_buf(r_blocks, tile_c), pltpu.SemaphoreType.DMA]
+                   pltpu.VMEM((wr, tile_c), jnp.uint32),   # residual words
+                   pltpu.SemaphoreType.DMA((n_copies,))]
         return _fee_call(kern, q, [_feature_major(xc, tile_c),
                                    _feature_major(xr, tile_c)], x_specs,
                          thr, alpha, beta, margin, seg=seg, tile_c=tile_c,
@@ -472,9 +508,11 @@ def fee_distance_tiered_pallas(q, xc, xr, threshold, alpha, beta, margin, *,
     (unsplit) layout — ``dfloat.split_config`` preserves per-feature formats,
     so outputs are bit-identical for any split.  The coarse tier is streamed
     through the automatic BlockSpec pipeline (it is the resident payload);
-    residual word spans stay in HBM and move only through the gated manual
-    DMAs of live tiles.  Degenerate splits (one tier empty) collapse to the
-    single-tier packed kernel on the non-empty bitstream.
+    the residual tier stays in HBM and moves only through manual DMAs, per
+    candidate tile: a tile with a live lane at the tier boundary fetches its
+    whole residual row span, a tile whose lanes all exited inside the coarse
+    tier fetches none of it.  Degenerate splits (one tier empty) collapse to
+    the single-tier packed kernel on the non-empty bitstream.
     """
     if coarse_cfg.dim == 0:
         return fee_distance_packed_pallas(
@@ -502,8 +540,9 @@ def fee_distance_packed_pallas(q, xp, threshold, alpha, beta, margin, *,
     bytes cross HBM; decoded features exist only in VMEM, one block at a time.
     Results are bit-compatible with ``fee_distance_pallas`` over
     ``dfloat.emulate_db`` data.  ``skip_dma=True`` keeps the bitstream in HBM
-    and fetches each live block's word span with a manual async copy —
-    exited tiles skip the remaining packed bursts entirely.
+    and fetches each block's word span with a manual async copy inside the
+    tile-exit gate — exited tiles skip the packed bursts of every later group
+    of ``GATE_BLOCKS`` blocks.
     """
     assert dfloat_cfg.dim == q.shape[0], (dfloat_cfg.dim, q.shape)
     c = xp.shape[0]

@@ -5,9 +5,10 @@ in interpret mode (kernel body executed in Python), which is the validation
 target per the build spec.  ``backend="jnp"`` selects the pure-jnp oracle —
 used both as the reference in tests and as the fast path for CPU benchmarks.
 ``backend="pallas_skip_dma"`` selects the manual-DMA kernels: feature blocks
-(or packed word spans) are fetched from HBM with async copies gated on the
-tile-exit flag, so exited tiles skip the remaining memory traffic, not just
-the compute.
+(or packed word spans) are fetched from HBM with async copies inside the
+tile-exit gate, which is asked once per group of
+``fee_distance.GATE_BLOCKS`` blocks, so exited tiles skip the memory traffic
+of the remaining groups, not just the compute.
 """
 from __future__ import annotations
 
@@ -103,13 +104,17 @@ def fee_distance_tiered(q, xc, xr, threshold, alpha, beta, margin, *,
                         tile_c: int = 128, lane_mask=None):
     """Tiered fused decode + early-exit distance: the resident coarse-tier
     rows ``xc`` (C, Wc) make the exit decision; residual-tier rows ``xr``
-    (C, Wr) are fetched (gated async copies on the Pallas path) only while a
-    tile still has live lanes.
+    (C, Wr) are fetched per candidate tile (128 lanes by default): on the
+    Pallas path a tile with a live lane at the tier boundary starts async
+    copies of its whole residual span there, and a tile whose lanes all
+    exited inside the coarse tier fetches none of it.
 
     Bit-identical to :func:`fee_distance_packed` over the parent layout's
     rows for any split point (``dfloat.split_config`` preserves per-feature
-    formats).  A lane fetched the residual tier iff ``segs_used >
-    coarse_cfg.dim // seg`` — exited lanes never pay residual bytes.
+    formats).  A lane *uses* the residual tier iff ``segs_used >
+    coarse_cfg.dim // seg`` — the accounting of the traffic models, in
+    which exited lanes never pay residual bytes; the kernel's copies move
+    whole tiles.
     """
     if _use_ref(backend):
         out = ref_ops.fee_distance_tiered_ref(

@@ -80,22 +80,37 @@ def test_skipdma_kernel_equals_baseline(metric):
         assert np.array_equal(np.asarray(g), np.asarray(w))
 
 
-@pytest.mark.parametrize("skip_dma", [False, True])
-def test_packed_kernel_matches_ref(skip_dma):
+# (candidates, width, tile_c, threshold): one 100-lane query over two
+# tiles; five tiles, the last ragged; a row past one exit-check group where
+# every lane exits at the first block, so every tile's later blocks (and, with
+# skip_dma, their fetches) are skipped
+PACKED_CASES = {"base": (100, 128, 64, None), "multi_tile": (300, 128, 64, None),
+                "all_exit": (150, 256, 64, -1.0)}
+
+
+@pytest.mark.parametrize("case,skip_dma", [
+    pytest.param(case, skip, id=str(skip) if case == "base" else f"{case}-{skip}")
+    for case in PACKED_CASES for skip in (False, True)])
+def test_packed_kernel_matches_ref(case, skip_dma):
     from repro.kernels import ref as ref_ops
     from repro.kernels.fee_distance import fee_distance_packed_pallas
 
-    q, x, thr, alpha, beta, margin = _kernel_inputs(c=100)
-    cfg = dfl.make_config(128, [(21, 6, 64), (14, 5, 64)], x)
+    c, d, tile_c, fixed_thr = PACKED_CASES[case]
+    q, x, thr, alpha, beta, margin = _kernel_inputs(c=c, d=d)
+    if fixed_thr is not None:
+        thr = jnp.float32(fixed_thr)
+    cfg = dfl.make_config(d, [(21, 6, d // 2), (14, 5, d // 2)], x)
     packed = jnp.asarray(dfl.pack_db(x, cfg))
     want = ref_ops.fee_distance_packed_ref(q, packed, thr, alpha, beta, margin,
                                            dfloat_cfg=cfg, seg=16, metric="l2")
     got = fee_distance_packed_pallas(q, packed, thr, alpha, beta, margin,
                                      dfloat_cfg=cfg, seg=16, metric="l2",
-                                     tile_c=64, skip_dma=skip_dma)
+                                     tile_c=tile_c, skip_dma=skip_dma)
     np.testing.assert_allclose(got[0], want[0], rtol=3e-5, atol=2e-4)
     assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
     assert np.array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    if case == "all_exit":
+        assert np.asarray(got[1]).all() and (np.asarray(got[2]) == 1).all()
 
 
 # ---------------------------------------------------------------------------

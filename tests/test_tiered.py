@@ -62,6 +62,69 @@ def test_all_interior_splits_bit_identical_to_packed(unit_db,
         np.testing.assert_array_equal(got.ids, ref.ids, err_msg=f"split={split}")
 
 
+# ---------------------------------------------------------------------------
+# the tiered kernel against its oracle
+# ---------------------------------------------------------------------------
+
+
+def _tile_pattern_inputs(rng, c, d, split, tile_c):
+    """Candidates whose tiles exit in turn: every lane of the first tile
+    inside the coarse tier, of the second inside the residual tier (past
+    the first exit-check group there), and none of the rest."""
+    x = rng.uniform(0.05, 0.15, (c, d)).astype(np.float32)
+    x[:tile_c, :split] += 10.0
+    x[tile_c : 2 * tile_c, split:] += 1.0
+    return x, np.zeros(d, np.float32), np.float32(100.0)
+
+
+@pytest.mark.parametrize("case", ["interior_split", "ragged_tiles",
+                                  "tile_exit_pattern"])
+def test_tiered_kernel_matches_ref(case):
+    import jax.numpy as jnp
+
+    from repro.kernels import ref as ref_ops
+    from repro.kernels.fee_distance import (GATE_BLOCKS,
+                                            fee_distance_tiered_pallas)
+
+    seg = 16
+    c, d, split, tile_c = {"interior_split": (40, 128, 48, 128),
+                           "ragged_tiles": (150, 192, 64, 64),
+                           "tile_exit_pattern": (91, 256, 32, 32)}[case]
+    n_segs, n_coarse = d // seg, split // seg
+    rng = np.random.default_rng(d + c)
+    if case == "tile_exit_pattern":
+        x, q, thr = _tile_pattern_inputs(rng, c, d, split, tile_c)
+        alpha = beta = np.ones(n_segs, np.float32)
+    else:
+        # a PCA-like decaying spectrum, so that the leading blocks decide
+        scale = (1.0 / np.arange(1, d + 1) ** 0.7).astype(np.float32)
+        x = rng.standard_normal((c, d)).astype(np.float32) * scale
+        q = x[0] + 0.3 * rng.standard_normal(d).astype(np.float32) * scale
+        thr = np.float32(np.quantile(((x - q) ** 2).sum(1), 0.3))
+        alpha = (1.0 + 1.0 / np.arange(1, n_segs + 1)).astype(np.float32)
+        beta = (1.0 + 0.2 / np.arange(1, n_segs + 1)).astype(np.float32)
+    cfg = dfl.make_config(d, [(18, 6, d // 2), (12, 4, d // 2)], x)
+    ccfg, rcfg = dfl.split_config(cfg, split)
+    xc, xr = (jnp.asarray(a) for a in dfl.pack_tiers(x, cfg, split))
+    args = (jnp.asarray(q), xc, xr, jnp.float32(thr), jnp.asarray(alpha),
+            jnp.asarray(beta), jnp.zeros(n_segs, jnp.float32))
+    kw = dict(coarse_cfg=ccfg, resid_cfg=rcfg, seg=seg)
+    want = ref_ops.fee_distance_tiered_ref(*args, **kw)
+    got = fee_distance_tiered_pallas(*args, tile_c=tile_c, **kw)
+    np.testing.assert_allclose(got[0], want[0], rtol=3e-5, atol=2e-4)
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert np.array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    rej, segs = np.asarray(got[1]), np.asarray(got[2])
+    if case == "tile_exit_pattern":
+        assert rej[:tile_c].all() and (segs[:tile_c] <= n_coarse).all()
+        mid = slice(tile_c, 2 * tile_c)
+        assert rej[mid].all() and (segs[mid] > n_coarse).all()
+        assert (segs[mid] < n_coarse + GATE_BLOCKS).all()
+        assert not rej[2 * tile_c :].any()
+    else:
+        assert rej.any() and not rej.all()
+
+
 def test_recall_matches_packed_operating_point(unit_db, unit_index_dfloat):
     """At the bench operating point the tiered recall must sit within 0.1 pt
     of packed (it is in fact bit-identical ids, so the delta is exactly 0)."""
