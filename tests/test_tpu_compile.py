@@ -167,7 +167,7 @@ def test_search_program_compiles_for_tpu(search_hlo, storage):
     assert "tpu_custom_call" in search_hlo(storage)
 
 
-@pytest.mark.parametrize("storage", ["packed", "tiered"])
+@pytest.mark.parametrize("storage", ["f32", "packed", "tiered"])
 def test_search_program_carries_stage_scopes(search_hlo, storage):
     """The compiled program's metadata names each hop stage, and its FEE
     kernel keeps the instruction name the trace reduction matches."""
