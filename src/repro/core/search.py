@@ -55,7 +55,7 @@ from repro.core import dfloat as dfl
 from repro.core import fee as fee_mod
 from repro.core.fee import FeeParams
 from repro.kernels import ops as kops
-from repro.obs import tracer
+from repro.obs import default_registry, tracer
 
 BIG = jnp.float32(3.0e38)
 
@@ -486,59 +486,115 @@ def make_searcher(vectors, adj, cfg: SearchConfig,
     return search
 
 
+@dataclasses.dataclass(frozen=True)
+class DeviceLevels:
+    """The upper HNSW levels, resident on the device for the entry descent.
+
+    ``levels`` holds one ``(rows, adj, down)`` triple per upper level, top
+    level first: the (Nl, D) f32 rows the greedy descent scores against,
+    the (Nl, M) int32 level-local adjacency, and the (Nl,) int32 map of each
+    level-local index to the next level down (the level's global ids for
+    the lowest upper level, so the last map yields base entry ids).
+    ``start`` is the int32 top-level index of the graph's entry (its global
+    id when there is no upper level); ``rows`` counts the rows of all
+    levels."""
+
+    levels: tuple
+    start: jax.Array
+    rows: int
+
+
+def _local_pos(ids: np.ndarray, targets: np.ndarray) -> np.ndarray:
+    """Index of each target in the sorted level ids, 0 where it is absent."""
+    pos = np.clip(np.searchsorted(ids, targets), 0, len(ids) - 1)
+    return np.where(ids[pos] == targets, pos, 0)
+
+
+def upload_levels(graph, fetch) -> DeviceLevels:
+    """Upload the upper levels of ``graph`` for :func:`descend`.
+
+    ``fetch(ids) -> (len(ids), D)`` supplies the f32 rows of a level's ids.
+    The down maps route each level's nodes into the next by
+    ``searchsorted`` on the sorted level ids, once per upload.  Adds the
+    bytes shipped to the ``search.descent_h2d_bytes`` counter."""
+    host = []
+    for level in range(len(graph.levels) - 1, 0, -1):
+        ids, adj = graph.levels[level]
+        # level ids are sorted by construction (graph.build_graph)
+        down = ids if level == 1 else _local_pos(graph.levels[level - 1][0],
+                                                 ids)
+        host.append((np.asarray(fetch(ids), np.float32),
+                     np.asarray(adj, np.int32), np.asarray(down, np.int32)))
+    entry = np.asarray([graph.entry])
+    start = _local_pos(graph.levels[-1][0], entry)[0] if host else entry[0]
+    default_registry().counter("search.descent_h2d_bytes").inc(
+        float(sum(a.nbytes for lvl in host for a in lvl)))
+    return DeviceLevels(
+        levels=tuple(tuple(jnp.asarray(a) for a in lvl) for lvl in host),
+        start=jnp.asarray(start, jnp.int32),
+        rows=sum(len(lvl[0]) for lvl in host))
+
+
 @partial(jax.jit, static_argnames=("metric",))
-def _greedy_level(vecs_l, adj_l, queries, cur, *, metric: str):
-    """One upper-layer greedy descent for a whole query batch.
+def _descend_levels(levels, start, queries, *, metric: str):
+    """Greedy top-down routing through every upper level in one program:
+    the rotated query batch -> (Q,) int32 base entry ids.
 
     A top-level jitted function (arrays are *arguments*, not closure
-    constants), so XLA caches one executable per (level shape, metric) and
-    repeated query batches never recompile.
-    """
+    constants), so XLA caches one executable per (level shapes, batch,
+    metric) and repeated query batches never recompile."""
 
-    def greedy(q, c):
-        def cond(s):
-            return s[2]
+    def greedy(vecs_l, adj_l):
+        def one(q, c):
+            def cond(s):
+                return s[2]
 
-        def body(s):
-            c, d, _ = s
-            nb = adj_l[c]
-            nd = fee_mod.exact_distance(q, vecs_l[nb], metric=metric)
-            j = jnp.argmin(nd)
-            better = nd[j] < d
-            return (jnp.where(better, nb[j], c), jnp.minimum(nd[j], d), better)
+            def body(s):
+                c, d, _ = s
+                nb = adj_l[c]
+                nd = fee_mod.exact_distance(q, vecs_l[nb], metric=metric)
+                j = jnp.argmin(nd)
+                better = nd[j] < d
+                return (jnp.where(better, nb[j], c), jnp.minimum(nd[j], d),
+                        better)
 
-        d0 = fee_mod.exact_distance(q, vecs_l[c][None], metric=metric)[0]
-        c, _, _ = jax.lax.while_loop(cond, body, (c, d0, jnp.bool_(True)))
-        return c
+            d0 = fee_mod.exact_distance(q, vecs_l[c][None], metric=metric)[0]
+            c, _, _ = jax.lax.while_loop(cond, body, (c, d0, jnp.bool_(True)))
+            return c
 
-    with jax.named_scope("descent.level"):
-        return jax.vmap(greedy)(queries, cur)
+        return jax.vmap(one)
+
+    cur = jnp.broadcast_to(start, queries.shape[:1])
+    for vecs_l, adj_l, down in levels:
+        with jax.named_scope("descent.level"):
+            cur = greedy(vecs_l, adj_l)(queries, cur)
+        cur = down[cur]
+    return cur
+
+
+def descend(levels: DeviceLevels, queries, metric: str) -> jax.Array:
+    """Enqueue the descent of a rotated query batch through resident levels;
+    returns the (Q,) int32 base entry ids as a device array, without
+    waiting for them.  One ``search.descent`` span (attrs ``levels``,
+    ``rows``) covers the enqueue."""
+    with tracer.span("search.descent", levels=len(levels.levels),
+                     rows=levels.rows):
+        return _descend_levels(levels.levels, levels.start,
+                               jnp.asarray(queries), metric=metric)
 
 
 def descend_entry(vectors, graph, queries, metric: str) -> np.ndarray:
-    """Greedy top-down routing through HNSW upper layers -> base entry ids,
-    one ``search.descent`` span (attrs ``level``, ``rows``) per level.
+    """Greedy top-down routing through HNSW upper layers -> base entry ids.
 
     ``vectors`` is either the dense (N, D) f32 array or a callable
     ``ids -> (len(ids), D) f32`` row provider — the latter lets packed-native
     indices materialize only the tiny upper-level subsets instead of a full
-    f32 copy of the DB.
+    f32 copy of the DB.  Uploads the levels and runs :func:`descend`; a
+    searcher that descends every batch keeps its :class:`DeviceLevels`
+    (``Index.device_levels``) instead.
     """
     fetch = vectors if callable(vectors) else (lambda ids: vectors[ids])
-    entries = np.full(len(queries), graph.entry, np.int64)
-    queries = jnp.asarray(queries)
-    for level in range(len(graph.levels) - 1, 0, -1):
-        ids, adj = graph.levels[level]
-        with tracer.span("search.descent", level=level, rows=len(ids)):
-            # level ids are sorted by construction (graph.build_graph)
-            pos = np.clip(np.searchsorted(ids, entries), 0, len(ids) - 1)
-            cur = np.where(ids[pos] == entries, pos, 0).astype(np.int32)
-            cur = np.asarray(_greedy_level(jnp.asarray(fetch(ids)),
-                                           jnp.asarray(adj, jnp.int32),
-                                           queries, jnp.asarray(cur),
-                                           metric=metric))
-            entries = ids[cur]
-    return entries.astype(np.int32)
+    return np.asarray(descend(upload_levels(graph, fetch), queries, metric))
 
 
 def search_graph(vectors, graph, queries, cfg: SearchConfig,

@@ -20,9 +20,12 @@ amortizes the cross-shard all-gather and packed shards hold ~3x more vectors
 per device), and the traced search that feeds the ndpsim engine (which
 consumes per-hop multi-node traces).
 
-With ``storage="packed"`` the hierarchy-descent stage still scores f32 rows,
-but only the tiny upper-level subsets are ever emulated — the full ``db_q``
-array is never materialized on host or device.
+The hierarchy descent scores f32 rows of the upper levels only: emulated
+Dfloat rows for the Dfloat stores (the full ``db_q`` array is never
+materialized on host or device), ``db_rot`` rows otherwise.  Those levels
+are uploaded once per index (``Index.device_levels``), and every batch
+descends all of them in one device program whose entries go straight to
+the search program, with no copy back to the host in between.
 
 Streaming-mutation snapshots (``repro.streaming.MutableIndex.freeze``) carry
 a tombstone bitmap and a generation counter; every backend masks tombstoned
@@ -96,28 +99,6 @@ def _base_vectors(index, params: SearchParams):
     return index.db_q if params.use_dfloat else index.db_rot
 
 
-def _descent_rows(index, params: SearchParams):
-    """f32 row provider for the upper-layer greedy descent.
-
-    Descent touches only the tiny upper-level subsets, so the packed/tiered
-    paths emulate just those rows instead of materializing a full f32 DB copy —
-    and memoize them per level (the fetched rows depend only on the fixed
-    level ids, not the queries), so repeated ``run()`` calls don't re-emulate."""
-    if params.use_dfloat:
-        if params.storage in ("packed", "tiered"):
-            cache = {}  # id(level_ids) -> rows; graph.levels arrays are fixed
-
-            def rows(ids):
-                key = id(ids)
-                if key not in cache:
-                    cache[key] = index.emulated_rows(ids)
-                return cache[key]
-
-            return rows
-        return index.emulated_rows
-    return lambda ids: index.db_rot[ids]
-
-
 def _dfloat_cfg(index, params: SearchParams):
     if params.storage == "packed":
         return index.dfloat_cfg
@@ -144,7 +125,7 @@ def local_searcher(index, params: SearchParams, *, fee=None):
         index.device_adjacency(), cfg, fee=_fee(index, params, fee),
         trace=params.trace, dfloat_cfg=_dfloat_cfg(index, params),
         tombstone=index.device_tombstone())
-    rows = _descent_rows(index, params)
+    levels = index.device_levels(params.use_dfloat)
 
     # bytes actually streamed per feature dim under this storage mode: the
     # packed/tiered bitstream moves total_bits/dim bits, dense f32 moves 4 B
@@ -162,11 +143,12 @@ def local_searcher(index, params: SearchParams, *, fee=None):
         # the search program both
         with tracer.span("search.pca"):
             qr = jnp.asarray(index.transform_queries(np.asarray(queries)))
-        entries = search_mod.descend_entry(rows, index.graph, qr, index.metric)
-        # dispatch returns once the program is enqueued; the wait blocks on
-        # the device and the copy back
+        # the descent and the search program are enqueued back to back; the
+        # entries stay on the device, and the wait blocks on both programs
+        # and the copy back
+        entries = search_mod.descend(levels, qr, index.metric)
         with tracer.span("search.dispatch"):
-            raw = searcher(qr, jnp.asarray(entries))
+            raw = searcher(qr, entries)
         with tracer.span("search.wait"):
             res = SearchResult.from_raw(raw)
         res.generation = index.generation
@@ -235,11 +217,11 @@ def sharded_searcher(index, params: SearchParams, *, mesh=None,
             fields += ("tombstone",)
         sdb = rt.ShardedDB(*(jax.device_put(getattr(sdb, f), getattr(sh, f))
                              for f in fields))
-    rows = _descent_rows(index, params)
+    levels = index.device_levels(params.use_dfloat)
 
     def run(queries) -> SearchResult:
         qr = index.transform_queries(np.asarray(queries))
-        entries = search_mod.descend_entry(rows, index.graph, qr, index.metric)
+        entries = np.asarray(search_mod.descend(levels, qr, index.metric))
         with jax.set_mesh(mesh):
             ids, dists = searcher(sdb, jnp.asarray(qr), jnp.asarray(entries))
         return SearchResult(ids=np.asarray(ids), dists=np.asarray(dists),

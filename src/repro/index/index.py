@@ -209,6 +209,19 @@ class Index:
             self._device["tombstone"] = jnp.asarray(self.tombstone, jnp.uint32)
         return self._device["tombstone"]
 
+    def device_levels(self, use_dfloat: bool = True):
+        """The upper graph levels on the device for the entry descent
+        (:class:`repro.core.search.DeviceLevels`), uploaded once per index —
+        so once per serving generation, each snapshot being its own
+        ``Index``.  The rows are the quantized f32 rows the Dfloat stores
+        decode to (``use_dfloat``), else ``db_rot``'s."""
+        key = ("levels", bool(use_dfloat))
+        if key not in self._device:
+            rows = (self.emulated_rows if use_dfloat
+                    else (lambda ids: self.db_rot[ids]))
+            self._device[key] = search_mod.upload_levels(self.graph, rows)
+        return self._device[key]
+
     def seed_device(self, key, arr) -> None:
         """Pre-populate the device-array cache (keys: ``("db", storage,
         use_dfloat)``, ``"adj"``, ``"tombstone"``).  The serving tier's

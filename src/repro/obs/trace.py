@@ -29,8 +29,8 @@ one back-dated span per request per stage::
                -> resolve
 
 One live span per batch per stage on the batcher thread, siblings that
-never nest (``SERVE_BATCH_STAGES``; ``search.descent`` once per upper
-level)::
+never nest (``SERVE_BATCH_STAGES``; ``search.descent`` is the enqueue of
+the one program that descends every upper level)::
 
     serve.take -> serve.admit -> serve.pad -> search.pca -> search.descent
       -> search.dispatch -> search.wait -> search.count -> serve.resolve

@@ -239,7 +239,7 @@ def test_disabled_after_enable_is_still_the_noop_singleton():
     tr = Tracer(enabled=True)
     tr.disable()
     assert tr.span("serve.pad") is _NOOP
-    assert tr.span("search.descent", level=2, rows=10) is _NOOP
+    assert tr.span("search.descent", levels=2, rows=10) is _NOOP
     with tr.span("serve.resolve"):
         pass
     assert tr.spans() == []

@@ -134,7 +134,8 @@ def test_disabled_tracing_serves_identically(unit_db, unit_index):
 def test_batch_stage_spans_tile_the_batcher_thread(unit_db, unit_index,
                                                    traced):
     """Every served batch yields each stage span once on the batcher thread
-    (``search.descent`` once per upper level); the spans never overlap and
+    (``search.descent`` enqueues the one program that descends every upper
+    level, attrs ``levels`` and ``rows``); the spans never overlap and
     cover >= 90 % of the thread's time from the end of ``serve.take`` to the
     end of ``serve.resolve``.  The per-request timelines still sum to
     ``total_ms``."""
@@ -154,17 +155,17 @@ def test_batch_stage_spans_tile_the_batcher_thread(unit_db, unit_index,
                   key=lambda s: s.t0_ns)
     levels = len(unit_index.graph.levels) - 1
     assert levels >= 1
+    rows = sum(len(ids) for ids, _ in unit_index.graph.levels[1:])
     for take in takes:
         resolve = next(s for s in mine if s.name == "serve.resolve"
                        and s.t0_ns >= take.t1_ns)
         batch = [s for s in mine if take.t0_ns <= s.t0_ns <= resolve.t0_ns]
         names = [s.name for s in batch]
         assert names == ["serve.take", "serve.admit", "serve.pad",
-                         "search.pca"] + ["search.descent"] * levels + [
-                         "search.dispatch", "search.wait", "search.count",
-                         "serve.resolve"], names
-        assert [s.attrs["level"] for s in batch
-                if s.name == "search.descent"] == list(range(levels, 0, -1))
+                         "search.pca", "search.descent", "search.dispatch",
+                         "search.wait", "search.count", "serve.resolve"], names
+        assert [s.attrs for s in batch if s.name == "search.descent"] == [
+            {"levels": levels, "rows": rows}]
         assert all(s.depth == 0 for s in batch)
         for a, b in zip(batch, batch[1:]):
             assert a.t1_ns <= b.t0_ns, (a, b)

@@ -184,11 +184,16 @@ def test_search_program_carries_stage_scopes(search_hlo, storage):
 
 
 def test_descent_level_carries_its_scope(one_chip):
-    n, d, m = 257, 128, 16
-    compiled = search_mod._greedy_level.lower(
-        _sds(one_chip, (n, d), jnp.float32), _sds(one_chip, (n, m), jnp.int32),
-        _sds(one_chip, (QUERIES, d), jnp.float32),
-        _sds(one_chip, (QUERIES,), jnp.int32), metric="l2").compile()
+    """The one descent program, over the three upper levels of a 40,000-row
+    sift graph (2,500, 156 and 24 rows, 20 neighbors each), compiles for
+    the chip and names each level's greedy loop ``descent.level``."""
+    d, m = 128, 20
+    levels = tuple((_sds(one_chip, (n, d), jnp.float32),
+                    _sds(one_chip, (n, m), jnp.int32),
+                    _sds(one_chip, (n,), jnp.int32)) for n in (24, 156, 2500))
+    compiled = search_mod._descend_levels.lower(
+        levels, _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (QUERIES, d), jnp.float32), metric="l2").compile()
     assert _scoped(compiled.as_text(), "descent.level")
 
 
