@@ -23,7 +23,6 @@ MODULES = [
     "benchmarks.fig25_ablation",
     "benchmarks.table4_pca_overhead",
     "benchmarks.kernel_bench",
-    "benchmarks.roofline",
 ]
 
 JSON_TARGETS = {

@@ -179,8 +179,7 @@ def collective_payload(cfg: SearchConfig, mc: int, c: int) -> dict:
 
 
 def make_sharded_searcher(mesh: Mesh, cfg: SearchConfig, n_total: int,
-                          fee: FeeParams | dict | None = None,
-                          n_bits_log2: int = 23, *,
+                          fee: FeeParams | dict | None = None, *,
                           dfloat_cfg: dfl.DfloatConfig | None = None,
                           tombstone=None, overlap: bool = False):
     """Returns search(db: ShardedDB, queries (Q, d), entries (Q,)) — a jit'd
@@ -201,14 +200,9 @@ def make_sharded_searcher(mesh: Mesh, cfg: SearchConfig, n_total: int,
     ``overlap=True`` selects the double-buffered pipeline (stale-threshold
     scoring, one-hop-deferred merge; recall-equivalent, not bit-identical).
 
-    ``n_bits_log2`` is accepted for backwards compatibility and ignored: the
-    visited set is now an exact per-shard bitmap over local slots, so there
-    is no hash space to size.
-
     Queries are padded by the wrapper to a multiple of (data x model) so
     every model shard owns an equal chunk; results come back in input order.
     """
-    del n_bits_log2
     model_axis = "model" if "model" in mesh.axis_names else mesh.axis_names[-1]
     data_axes = tuple(n for n in mesh.axis_names if n != model_axis)
     c = mesh.shape[model_axis]

@@ -1,1 +1,1 @@
-from repro.ft import checkpoint, elastic  # noqa: F401
+from repro.ft import checkpoint  # noqa: F401

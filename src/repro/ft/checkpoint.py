@@ -1,9 +1,8 @@
 """Mesh-agnostic checkpointing: save/restore of arbitrary pytrees.
 
-Design (DESIGN.md §7):
+Design:
   * arrays are saved in their GLOBAL logical shape (device_get gathers
-    shards), so a checkpoint written on a 256-chip mesh restores onto 4
-    chips or 512 — this is what makes elastic scaling trivial;
+    shards), so a checkpoint does not depend on the mesh that wrote it;
   * atomic AND crash-ordered: write into ``<dir>.tmp`` (fsync), rename the
     previous checkpoint aside to ``<dir>.old``, rename the replacement in,
     then remove the old — the last durable state is never deleted before the
@@ -13,7 +12,7 @@ Design (DESIGN.md §7):
     (``repro.resilience.checksum``); ``restore`` re-checks every array and
     raises :class:`~repro.resilience.CorruptArtifactError` on a flipped bit
     or torn tail instead of returning garbage;
-  * async: the serialize+write runs on a writer thread (training continues);
+  * async: the serialize+write runs on a writer thread (the caller continues);
   * manifest carries step + user metadata for restart logic.
 
 Crash windows (all fault-injectable, see ``repro.resilience.faults``):
@@ -167,10 +166,8 @@ def latest_step(base_dir: str | Path) -> int | None:
     return all_steps[-1] if all_steps else None
 
 
-def restore(ckpt_dir: str | Path, abstract_tree, shardings=None):
-    """Restore into the structure of ``abstract_tree``; if ``shardings``
-    (matching pytree of NamedSharding) is given, place shards directly on the
-    target mesh — the mesh may differ from the one that wrote the ckpt.
+def restore(ckpt_dir: str | Path, abstract_tree):
+    """Restore into the structure of ``abstract_tree``.
 
     Verifies every array against the manifest's recorded checksums (when
     present) and raises :class:`~repro.resilience.CorruptArtifactError` on
@@ -209,9 +206,5 @@ def restore(ckpt_dir: str | Path, abstract_tree, shardings=None):
     missing = set(flat_abs) - set(host)
     if missing:
         raise ValueError(f"checkpoint missing keys: {sorted(missing)[:5]} ...")
-    if shardings is not None:
-        flat_sh, _ = _flatten(shardings)
-        vals = [jax.device_put(host[k], flat_sh[k]) for k in flat_abs]
-    else:
-        vals = [jax.numpy.asarray(host[k]) for k in flat_abs]
+    vals = [jax.numpy.asarray(host[k]) for k in flat_abs]
     return jax.tree_util.tree_unflatten(treedef, vals), manifest

@@ -162,7 +162,7 @@ def local_searcher(index, params: SearchParams, *, fee=None):
 
 def sharded_searcher(index, params: SearchParams, *, mesh=None,
                      n_shards: int | None = None, owner_policy: str = "shuffle",
-                     seed: int = 0, n_bits_log2: int = 23, fee=None,
+                     seed: int = 0, fee=None,
                      owner=None, overlap: bool = False):
     """Query-owner-sharded DaM retrieval (paper Fig. 12): vectors row-sharded
     over the ``model`` axis, neighbor lists pre-partitioned by owner, queries
@@ -206,7 +206,6 @@ def sharded_searcher(index, params: SearchParams, *, mesh=None,
     with jax.set_mesh(mesh):
         searcher = rt.make_sharded_searcher(mesh, cfg, index.n,
                                             fee=_fee(index, params, fee),
-                                            n_bits_log2=n_bits_log2,
                                             dfloat_cfg=_dfloat_cfg(index, params),
                                             tombstone=tomb is not None,
                                             overlap=overlap)

@@ -10,29 +10,13 @@ MutableIndex when ``--mutate`` asks for live churn, starts the server
 restarts), replays an open-loop arrival process, and prints /
 writes the latency, goodput and hot-swap accounting.  ``--check-*`` flags
 turn the run into a gate (non-zero exit on violation) for CI.
-
-The pre-existing LM prefill+decode smoke path is kept behind ``--decode``:
-
-  PYTHONPATH=src python -m repro.launch.serve --decode --arch llama3.2-1b \
-      --smoke --devices 8 --batch 4 --prompt-len 64 --gen 32
 """
 import argparse
 import json
-import os
 import sys
 
 
 def main(argv=None):
-    argv = sys.argv[1:] if argv is None else argv
-    if "--decode" in argv:
-        return _decode_main([a for a in argv if a != "--decode"])
-    return _serve_main(argv)
-
-
-# ---------------------------------------------------------------------------
-# ANNS serving
-# ---------------------------------------------------------------------------
-def _serve_main(argv):
     ap = argparse.ArgumentParser(description="online ANNS serving driver")
     ap.add_argument("--dataset", default="unit")
     ap.add_argument("--m", type=int, default=8, help="graph degree at build")
@@ -195,79 +179,6 @@ def _gate(args, s) -> int:
     if rc == 0 and (args.check_no_failures or args.check_p99_ms is not None):
         print("checks passed")
     return rc
-
-
-# ---------------------------------------------------------------------------
-# LM prefill + decode smoke (the pre-serving-subsystem path, kept verbatim)
-# ---------------------------------------------------------------------------
-def _decode_main(argv):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3.2-1b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--devices", type=int, default=0)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=64)
-    ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    args = ap.parse_args(argv)
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
-
-    import time
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro import configs as C
-    from repro.models.registry import get_model
-
-    cfg = C.get_smoke(args.arch) if args.smoke else C.get_config(args.arch)
-    api = get_model(cfg)
-    params = api.init(jax.random.key(0))
-    rng = np.random.default_rng(0)
-
-    kv_len = args.prompt_len + args.gen
-    if cfg.is_encdec:
-        batch = dict(frames=jnp.asarray(
-            rng.standard_normal((args.batch, args.prompt_len, cfg.d_model)), jnp.float32))
-    elif cfg.frontend == "vision":
-        batch = dict(
-            prefix_embeds=jnp.asarray(rng.standard_normal(
-                (args.batch, cfg.frontend_tokens, cfg.d_model)), jnp.float32),
-            tokens=jnp.asarray(rng.integers(0, cfg.vocab, (args.batch, args.prompt_len)),
-                               jnp.int32))
-    else:
-        batch = dict(tokens=jnp.asarray(
-            rng.integers(0, cfg.vocab, (args.batch, args.prompt_len)), jnp.int32))
-
-    t0 = time.perf_counter()
-    logits, cache = api.prefill(params, batch, kv_len)
-    logits.block_until_ready()
-    t_prefill = time.perf_counter() - t0
-
-    decode = jax.jit(api.decode)
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    out_tokens = [np.asarray(tok)]
-    t0 = time.perf_counter()
-    key = jax.random.key(1)
-    for i in range(args.gen - 1):
-        logits, cache = decode(params, cache, tok)
-        if args.temperature > 0:
-            key, k2 = jax.random.split(key)
-            tok = jax.random.categorical(k2, logits / args.temperature).astype(jnp.int32)
-        else:
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        out_tokens.append(np.asarray(tok))
-    jax.block_until_ready(tok)
-    t_decode = time.perf_counter() - t0
-
-    toks = np.stack(out_tokens, 1)
-    print(f"prefill: {t_prefill*1e3:.1f} ms for {args.batch}x{args.prompt_len}")
-    print(f"decode:  {t_decode*1e3:.1f} ms for {args.gen-1} steps "
-          f"({(args.gen-1)*args.batch/max(t_decode,1e-9):.0f} tok/s)")
-    print("sample token ids:", toks[0, :12].tolist())
-    return 0
 
 
 if __name__ == "__main__":

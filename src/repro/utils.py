@@ -1,11 +1,8 @@
-"""Small shared utilities: artifact caching, timing, tree sizes."""
+"""Small shared utilities: the checkout root and the artifact cache."""
 from __future__ import annotations
 
 import hashlib
-import json
 import os
-import time
-from contextlib import contextmanager
 from pathlib import Path
 
 import numpy as np
@@ -30,33 +27,3 @@ def cached_npz(key: str, builder):
     out = builder()
     np.savez(p, **out)
     return out
-
-
-def cached_json(key: str, builder):
-    p = cache_path(key, ".json")
-    if p.exists():
-        return json.loads(p.read_text())
-    out = builder()
-    p.write_text(json.dumps(out))
-    return out
-
-
-@contextmanager
-def timer(name: str, sink: dict | None = None):
-    t0 = time.perf_counter()
-    yield
-    dt = time.perf_counter() - t0
-    if sink is not None:
-        sink[name] = sink.get(name, 0.0) + dt
-
-
-def tree_bytes(tree) -> int:
-    import jax
-
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
-
-
-def tree_params(tree) -> int:
-    import jax
-
-    return sum(x.size for x in jax.tree.leaves(tree))

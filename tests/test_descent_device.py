@@ -62,29 +62,33 @@ def oracle_entries(fetch, graph, queries, metric):
     return entries.astype(np.int32)
 
 
-def _graph(rot, sizes, nested, m=8, seed=0):
+def _graph(rot, sizes, nested, metric, m=8, seed=0):
     """The index's base level under upper levels of the given sizes."""
     rng = np.random.default_rng(seed)
-    base = gmod.build_graph(rot, m=m, upper_branch=len(rot), seed=seed)
+    base = gmod.build_graph(rot, m=m, metric=metric, upper_branch=len(rot),
+                            seed=seed)
     levels, ids = list(base.levels), np.arange(len(rot))
     for size in sizes:
         pool = ids if nested else np.arange(len(rot))
         ids = np.sort(rng.choice(pool, size, replace=False))
-        adj = gmod._knn_adjacency(rot[ids], min(m, size - 1), "l2")
+        adj = gmod._knn_adjacency(rot[ids], min(m, size - 1), metric)
         adj = gmod._add_long_edges(adj, rng, 2)
         levels.append((ids.astype(np.int32), adj.astype(np.int32)))
     return gmod.GraphIndex(levels=levels, entry=int(levels[-1][0][0]), m=m)
 
 
 @pytest.fixture(scope="module")
-def indexes(unit_index_dfloat):
-    """The Dfloat unit index under each graph of ``GRAPHS``, each with its
-    own (empty) device and searcher caches."""
-    idx = unit_index_dfloat
-    return {name: dataclasses.replace(
-                idx, graph=_graph(idx.db_rot, sizes, name != "loose"),
-                _device={}, _searchers={})
-            for name, sizes in GRAPHS.items()}
+def indexes(unit_index_dfloat, unit_ip_index_dfloat):
+    """The Dfloat unit index under each graph of ``GRAPHS``, and the
+    inner-product one under the "deep" graph ("deep-ip"), each with its own
+    (empty) device and searcher caches."""
+    def under(idx, name):
+        graph = _graph(idx.db_rot, GRAPHS[name], name != "loose", idx.metric)
+        return dataclasses.replace(idx, graph=graph, _device={},
+                                   _searchers={})
+
+    return {**{name: under(unit_index_dfloat, name) for name in GRAPHS},
+            "deep-ip": under(unit_ip_index_dfloat, "deep")}
 
 
 def _rows(idx, params):
@@ -102,15 +106,15 @@ def test_graphs_have_the_levels_named(indexes):
     assert not np.isin(mid, lo).all()       # the fallback to index 0 is hit
 
 
-@pytest.mark.parametrize("graph", list(GRAPHS))
+@pytest.mark.parametrize("graph", [*GRAPHS, "deep-ip"])
 @pytest.mark.parametrize("bucket", [1, 32])
 @pytest.mark.parametrize("store", list(STORES))
-def test_descend_entry_matches_host_loop(unit_db, indexes, store, bucket,
-                                         graph):
+def test_descend_entry_matches_host_loop(unit_db, unit_ip_db, indexes, store,
+                                         bucket, graph):
     """``descend_entry`` (levels uploaded, one program) returns the entries
     of the per-level host loop, bit for bit."""
     idx, params = indexes[graph], STORES[store]
-    q = _queries(unit_db, idx, bucket)
+    q = _queries(unit_ip_db if idx.metric == "ip" else unit_db, idx, bucket)
     want = oracle_entries(_rows(idx, params), idx.graph, q, idx.metric)
     got = search_mod.descend_entry(_rows(idx, params), idx.graph, q,
                                    idx.metric)

@@ -9,6 +9,15 @@ import pytest
 from repro.index import Index, IndexSpec, SearchParams, SearchResult
 
 PARAMS = SearchParams(ef=48, k=10, use_dfloat=False)
+STORAGES = ("f32", "packed", "tiered")
+# every storage under each metric, named as in the other test files
+STORE_METRIC = [*(pytest.param(s, "l2", id=s) for s in STORAGES),
+                *(pytest.param(s, "ip", id=f"{s}-ip") for s in STORAGES)]
+
+
+def _params(storage):
+    return dataclasses.replace(PARAMS, storage=storage,
+                               use_dfloat=storage != "f32")
 
 
 def _overlap(a: np.ndarray, b: np.ndarray) -> float:
@@ -52,19 +61,22 @@ def test_search_params_validation(unit_index):
 # ---------------------------------------------------------------------------
 
 
-def test_save_load_round_trip(unit_db, unit_index, tmp_path):
-    path = unit_index.save(tmp_path / "idx.naszip")
+@pytest.mark.parametrize("storage,metric", STORE_METRIC)
+def test_save_load_round_trip(unit_store, storage, metric, tmp_path):
+    db, idx = unit_store(storage, metric)
+    params = _params(storage)
+    path = idx.save(tmp_path / "idx.naszip")
     loaded = Index.load(path)
 
-    assert loaded.spec == unit_index.spec
-    assert loaded.dfloat_cfg == unit_index.dfloat_cfg
+    assert loaded.spec == idx.spec
+    assert loaded.dfloat_cfg == idx.dfloat_cfg
     for f in ("alpha", "beta", "margin", "var_k"):
         np.testing.assert_array_equal(getattr(loaded.fee, f),
-                                      getattr(unit_index.fee, f))
-    np.testing.assert_array_equal(loaded.db_packed, unit_index.db_packed)
+                                      getattr(idx.fee, f))
+    np.testing.assert_array_equal(loaded.db_packed, idx.db_packed)
 
-    ref = unit_index.search(unit_db.queries, PARAMS)
-    got = loaded.search(unit_db.queries, PARAMS)
+    ref = idx.search(db.queries, params)
+    got = loaded.search(db.queries, params)
     np.testing.assert_array_equal(got.ids, ref.ids)
     np.testing.assert_array_equal(got.dists, ref.dists)
 
@@ -87,17 +99,13 @@ def _single_device_mesh():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-def test_local_sharded_parity_l2(unit_db, unit_index):
-    ref = unit_index.searcher("local", PARAMS)(unit_db.queries)
-    sh = unit_index.searcher("sharded", PARAMS,
-                             mesh=_single_device_mesh())(unit_db.queries)
-    assert _overlap(sh.ids, ref.ids) >= 0.95
-
-
-def test_local_sharded_parity_ip(unit_ip_db, unit_ip_index):
-    ref = unit_ip_index.searcher("local", PARAMS)(unit_ip_db.queries)
-    sh = unit_ip_index.searcher("sharded", PARAMS,
-                                mesh=_single_device_mesh())(unit_ip_db.queries)
+@pytest.mark.parametrize("storage,metric", STORE_METRIC)
+def test_local_sharded_parity(unit_store, storage, metric):
+    db, idx = unit_store(storage, metric)
+    params = _params(storage)
+    ref = idx.searcher("local", params)(db.queries)
+    sh = idx.searcher("sharded", params,
+                      mesh=_single_device_mesh())(db.queries)
     assert _overlap(sh.ids, ref.ids) >= 0.95
 
 

@@ -88,23 +88,29 @@ PACKED_CASES = {"base": (100, 128, 64, None), "multi_tile": (300, 128, 64, None)
                 "all_exit": (150, 256, 64, -1.0)}
 
 
-@pytest.mark.parametrize("case,skip_dma", [
-    pytest.param(case, skip, id=str(skip) if case == "base" else f"{case}-{skip}")
-    for case in PACKED_CASES for skip in (False, True)])
-def test_packed_kernel_matches_ref(case, skip_dma):
+@pytest.mark.parametrize("case,skip_dma,metric", [
+    *(pytest.param(case, skip, "l2",
+                   id=str(skip) if case == "base" else f"{case}-{skip}")
+      for case in PACKED_CASES for skip in (False, True)),
+    *(pytest.param(case, False, "ip",
+                   id="ip" if case == "base" else f"{case}-ip")
+      for case in PACKED_CASES)])
+def test_packed_kernel_matches_ref(case, skip_dma, metric):
     from repro.kernels import ref as ref_ops
     from repro.kernels.fee_distance import fee_distance_packed_pallas
 
     c, d, tile_c, fixed_thr = PACKED_CASES[case]
-    q, x, thr, alpha, beta, margin = _kernel_inputs(c=c, d=d)
+    q, x, thr, alpha, beta, margin = _kernel_inputs(c=c, d=d, metric=metric)
     if fixed_thr is not None:
-        thr = jnp.float32(fixed_thr)
+        # l2 scores are >= 0; an ip score can be any float, so its threshold
+        # sits below every one
+        thr = jnp.float32(fixed_thr if metric == "l2" else -3e38)
     cfg = dfl.make_config(d, [(21, 6, d // 2), (14, 5, d // 2)], x)
     packed = jnp.asarray(dfl.pack_db(x, cfg))
     want = ref_ops.fee_distance_packed_ref(q, packed, thr, alpha, beta, margin,
-                                           dfloat_cfg=cfg, seg=16, metric="l2")
+                                           dfloat_cfg=cfg, seg=16, metric=metric)
     got = fee_distance_packed_pallas(q, packed, thr, alpha, beta, margin,
-                                     dfloat_cfg=cfg, seg=16, metric="l2",
+                                     dfloat_cfg=cfg, seg=16, metric=metric,
                                      tile_c=tile_c, skip_dma=skip_dma)
     np.testing.assert_allclose(got[0], want[0], rtol=3e-5, atol=2e-4)
     assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))

@@ -32,12 +32,13 @@ def test_dfloat_search_recall(unit_db, unit_index_dfloat):
             <= 16), "compression should not exceed fp32 bursts (64d -> 16)"
 
 
-def test_ip_metric_search(unit_ip_db, unit_ip_index):
-    idx = unit_ip_index
-    res = idx.evaluate(unit_ip_db, SearchParams(ef=96, k=10, use_fee=True,
-                                                use_dfloat=False, trace=True))
-    base = idx.evaluate(unit_ip_db, SearchParams(ef=96, k=10, use_fee=False,
-                                                 use_dfloat=False, trace=True))
+@pytest.mark.parametrize("storage", ["f32", "packed", "tiered"])
+def test_ip_metric_search(unit_store, storage):
+    db, idx = unit_store(storage, "ip")
+    kw = dict(ef=96, k=10, storage=storage, use_dfloat=storage != "f32",
+              trace=True)
+    res = idx.evaluate(db, SearchParams(use_fee=True, **kw))
+    base = idx.evaluate(db, SearchParams(use_fee=False, **kw))
     assert res["recall"] >= base["recall"] - 0.03
     assert res["dims_per_eval"] <= base["dims_per_eval"]
 

@@ -23,6 +23,7 @@ from repro.serve.request import Request
 from repro.streaming import MutableIndex
 
 K = 10
+STORAGES = ("f32", "packed", "tiered")
 
 
 def _direct(idx, q, cfg, ef, k, storage="f32", bucket=None):
@@ -134,16 +135,17 @@ def test_bucket_padding_batch_of_1_vs_32(unit_db, unit_index):
     np.testing.assert_allclose(dists, res.dists[:, :K], rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("storage", ["f32", "packed"])
-def test_batched_mixed_traffic_bit_identical(unit_db, unit_index,
-                                             unit_index_dfloat, storage):
+@pytest.mark.parametrize("storage,metric", [
+    *(pytest.param(s, "l2", id=s) for s in STORAGES),
+    *(pytest.param(s, "ip", id=f"{s}-ip") for s in STORAGES)])
+def test_batched_mixed_traffic_bit_identical(unit_store, storage, metric):
     """Mixed k/ef traffic through the live batcher == one-by-one searches."""
-    idx = unit_index_dfloat if storage == "packed" else unit_index
+    db, idx = unit_store(storage, metric)
     cfg = ServeConfig(ef_buckets=(16, 32), batch_buckets=(1, 4, 8), k_max=K,
-                      storages=(storage,), use_dfloat=storage == "packed",
+                      storages=(storage,), use_dfloat=storage != "f32",
                       slo_ms=5000.0)
     with Server(idx, cfg) as srv:
-        cases = [(unit_db.queries[i], [16, 32, 48][i % 3], [3, 7, K][i % 3])
+        cases = [(db.queries[i], [16, 32, 48][i % 3], [3, 7, K][i % 3])
                  for i in range(24)]
         futs = [srv.submit(q, k=k, ef=ef) for q, ef, k in cases]
         resps = [f.result(timeout=60) for f in futs]
@@ -162,20 +164,35 @@ def test_batched_mixed_traffic_bit_identical(unit_db, unit_index,
 # ---------------------------------------------------------------------------
 # hot swap: zero failures, consistent generations, delta uploads
 # ---------------------------------------------------------------------------
-def test_hot_swap_mid_stream_consistent(unit_db, unit_index):
+@pytest.mark.parametrize("storage", STORAGES)
+def test_hot_swap_mid_stream_consistent(unit_store, storage):
+    db, idx = unit_store(storage)
     cfg = ServeConfig(ef_buckets=(32,), batch_buckets=(1, 4), k_max=K,
+                      storages=(storage,), use_dfloat=storage != "f32",
                       slo_ms=5000.0, swap_poll_s=0.05)
-    mi = MutableIndex(unit_index, ef_build=32, sub_batch=64)
+    mi = MutableIndex(idx, ef_build=32, sub_batch=64)
     rng = np.random.default_rng(0)
 
     def churn():
-        mi.append(rng.standard_normal((4, unit_db.dim)).astype(np.float32))
-        mi.delete(rng.integers(0, unit_db.n, 2))
+        mi.append(rng.standard_normal((4, db.dim)).astype(np.float32))
+        mi.delete(rng.integers(0, db.n, 2))
+
+    churn()             # compile the write path before the window,
+    mi.freeze()         # and drain its repair
+    # a churn installs up to two generations (after its append, and after
+    # its delete and repair): with the first, three churns make at most 7,
+    # so every generation a response names is still in the server's
+    # 8-deep history
+    window = iter(range(3))
+
+    def churn_in_window():
+        if next(window, None) is not None:
+            churn()
 
     with Server(mi, cfg) as srv:
-        resps = run_load(srv, unit_db.queries, rps=60, duration_s=3.0,
+        resps = run_load(srv, db.queries, rps=60, duration_s=3.0,
                          ef=32, k=K, deadline_ms=5000.0, seed=1,
-                         mutate_fn=churn, mutate_every_s=0.3)
+                         mutate_fn=churn_in_window, mutate_every_s=0.3)
         history = dict(srv.history)
         swap_summary = srv.metrics.summary().get("swaps", {})
 
@@ -193,8 +210,8 @@ def test_hot_swap_mid_stream_consistent(unit_db, unit_index):
         by_gen.setdefault(r.generation, (i, r))
     for gen, (i, r) in by_gen.items():
         snap = history[gen]
-        q = unit_db.queries[i % len(unit_db.queries)][None]
-        ref_ids, ref_dists = _direct(snap, q, cfg, 32, K,
+        q = db.queries[i % len(db.queries)][None]
+        ref_ids, ref_dists = _direct(snap, q, cfg, 32, K, storage,
                                      bucket=r.batch_bucket)
         np.testing.assert_array_equal(r.ids, ref_ids[0])
         np.testing.assert_array_equal(r.dists, ref_dists[0])

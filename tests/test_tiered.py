@@ -67,19 +67,24 @@ def test_all_interior_splits_bit_identical_to_packed(unit_db,
 # ---------------------------------------------------------------------------
 
 
-def _tile_pattern_inputs(rng, c, d, split, tile_c):
+def _tile_pattern_inputs(rng, c, d, split, tile_c, metric):
     """Candidates whose tiles exit in turn: every lane of the first tile
     inside the coarse tier, of the second inside the residual tier (past
-    the first exit-check group there), and none of the rest."""
+    the first exit-check group there), and none of the rest.  The query is
+    zero under l2 and all -1 under ip, so that a lane's score grows with
+    its features either way."""
     x = rng.uniform(0.05, 0.15, (c, d)).astype(np.float32)
     x[:tile_c, :split] += 10.0
     x[tile_c : 2 * tile_c, split:] += 1.0
-    return x, np.zeros(d, np.float32), np.float32(100.0)
+    q = np.full(d, 0.0 if metric == "l2" else -1.0, np.float32)
+    return x, q, np.float32(100.0)
 
 
-@pytest.mark.parametrize("case", ["interior_split", "ragged_tiles",
-                                  "tile_exit_pattern"])
-def test_tiered_kernel_matches_ref(case):
+@pytest.mark.parametrize("case,metric", [
+    pytest.param(case, metric, id=case if metric == "l2" else f"{case}-ip")
+    for metric in ("l2", "ip")
+    for case in ("interior_split", "ragged_tiles", "tile_exit_pattern")])
+def test_tiered_kernel_matches_ref(case, metric):
     import jax.numpy as jnp
 
     from repro.kernels import ref as ref_ops
@@ -93,14 +98,15 @@ def test_tiered_kernel_matches_ref(case):
     n_segs, n_coarse = d // seg, split // seg
     rng = np.random.default_rng(d + c)
     if case == "tile_exit_pattern":
-        x, q, thr = _tile_pattern_inputs(rng, c, d, split, tile_c)
+        x, q, thr = _tile_pattern_inputs(rng, c, d, split, tile_c, metric)
         alpha = beta = np.ones(n_segs, np.float32)
     else:
         # a PCA-like decaying spectrum, so that the leading blocks decide
         scale = (1.0 / np.arange(1, d + 1) ** 0.7).astype(np.float32)
         x = rng.standard_normal((c, d)).astype(np.float32) * scale
         q = x[0] + 0.3 * rng.standard_normal(d).astype(np.float32) * scale
-        thr = np.float32(np.quantile(((x - q) ** 2).sum(1), 0.3))
+        score = ((x - q) ** 2).sum(1) if metric == "l2" else -(x @ q)
+        thr = np.float32(np.quantile(score, 0.3))
         alpha = (1.0 + 1.0 / np.arange(1, n_segs + 1)).astype(np.float32)
         beta = (1.0 + 0.2 / np.arange(1, n_segs + 1)).astype(np.float32)
     cfg = dfl.make_config(d, [(18, 6, d // 2), (12, 4, d // 2)], x)
@@ -108,7 +114,7 @@ def test_tiered_kernel_matches_ref(case):
     xc, xr = (jnp.asarray(a) for a in dfl.pack_tiers(x, cfg, split))
     args = (jnp.asarray(q), xc, xr, jnp.float32(thr), jnp.asarray(alpha),
             jnp.asarray(beta), jnp.zeros(n_segs, jnp.float32))
-    kw = dict(coarse_cfg=ccfg, resid_cfg=rcfg, seg=seg)
+    kw = dict(coarse_cfg=ccfg, resid_cfg=rcfg, seg=seg, metric=metric)
     want = ref_ops.fee_distance_tiered_ref(*args, **kw)
     got = fee_distance_tiered_pallas(*args, tile_c=tile_c, **kw)
     np.testing.assert_allclose(got[0], want[0], rtol=3e-5, atol=2e-4)

@@ -2,7 +2,7 @@
 dispatch to is compiled — not run — for one chip of a described v5e:2x2
 topology, at the real widths of the sift/bigann (D=128) and gist (D=960)
 shapes, vmapped over a query batch as the search loop calls it.  The whole
-local search program is compiled once per storage as well.
+local search program is compiled once per storage and metric as well.
 
 Interpret mode (every other kernel test) cannot see what the chip's compiler
 refuses: block shapes off the (8, 128) tiling, unsupported in-kernel layout
@@ -44,6 +44,10 @@ LAYOUTS = {128: [(21, 6, 48), (14, 5, 80)],
 SEARCH_SCOPES = ("search.init", "hop.frontier", "hop.gather", "hop.score",
                  "hop.merge")
 TRACE_REDUCE = Path(__file__).resolve().parents[1] / "bench" / "trace_reduce.py"
+STORAGES = ("f32", "packed", "tiered")
+# every storage under each metric; the l2 cases keep their plain ids
+STORE_METRIC = [*(pytest.param(s, "l2", id=s) for s in STORAGES),
+                *(pytest.param(s, "ip", id=f"{s}-ip") for s in STORAGES)]
 
 
 @pytest.fixture(scope="module")
@@ -77,11 +81,11 @@ def _dfloat(d):
     return cfg, dfl.split_config(cfg, d // 2)
 
 
-def _kernel_case(variant, d):
+def _kernel_case(variant, d, metric):
     """(single-query kernel fn, candidate operand shapes/dtypes)."""
     cfg, (ccfg, rcfg) = _dfloat(d)
     w = dfl.packed_words(cfg)
-    kw = dict(seg=SEG, interpret=False)
+    kw = dict(seg=SEG, metric=metric, interpret=False)
     if variant == "f32":
         return (lambda q, x, *f: fd.fee_distance_pallas(q, x, *f, **kw),
                 [((LANES, d), jnp.float32)])
@@ -101,10 +105,12 @@ def _kernel_case(variant, d):
 
 
 @pytest.mark.parametrize("d", sorted(LAYOUTS))
-@pytest.mark.parametrize("variant", ["f32", "f32_skip_dma", "packed",
-                                     "packed_skip_dma", "tiered"])
-def test_fee_kernel_compiles_for_tpu(one_chip, variant, d):
-    fn, xs = _kernel_case(variant, d)
+@pytest.mark.parametrize("variant,metric", [
+    *(pytest.param(v, "l2", id=v) for v in ("f32", "f32_skip_dma", "packed",
+                                            "packed_skip_dma", "tiered")),
+    *(pytest.param(v, "ip", id=f"{v}-ip") for v in STORAGES)])
+def test_fee_kernel_compiles_for_tpu(one_chip, variant, metric, d):
+    fn, xs = _kernel_case(variant, d, metric)
     n_segs = d // SEG
 
     def batch(q, *args):
@@ -131,24 +137,24 @@ def test_dfloat_unpack_compiles_for_tpu(one_chip, d):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_search(one_chip, storage):
+def _compile_search(one_chip, storage, metric):
     """The whole jitted local search (vmap over queries of the hop
     while_loop) with ``fee_backend="auto"`` dispatching to the kernels,
     compiled for one chip; returns the compiled HLO text."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(kops, "_on_tpu", lambda: True)
-        return _lower_search(one_chip, storage).compile().as_text()
+        return _lower_search(one_chip, storage, metric).compile().as_text()
 
 
 @pytest.fixture(scope="module")
 def search_hlo(one_chip):
-    """``storage -> compiled HLO text``, each storage compiled once."""
+    """``(storage, metric) -> compiled HLO text``, each pair compiled once."""
     cache = {}
 
-    def get(storage):
-        if storage not in cache:
-            cache[storage] = _compile_search(one_chip, storage)
-        return cache[storage]
+    def get(storage, metric):
+        if (storage, metric) not in cache:
+            cache[storage, metric] = _compile_search(one_chip, storage, metric)
+        return cache[storage, metric]
 
     return get
 
@@ -160,15 +166,15 @@ def _scoped(hlo: str, scope: str) -> bool:
     return re.search(pattern, hlo) is not None
 
 
-@pytest.mark.parametrize("storage", ["f32", "packed", "tiered"])
-def test_search_program_compiles_for_tpu(search_hlo, storage):
+@pytest.mark.parametrize("storage,metric", STORE_METRIC)
+def test_search_program_compiles_for_tpu(search_hlo, storage, metric):
     """The whole jitted local search (vmap over queries of the hop
     while_loop) with ``fee_backend="auto"`` dispatching to the kernels."""
-    assert "tpu_custom_call" in search_hlo(storage)
+    assert "tpu_custom_call" in search_hlo(storage, metric)
 
 
-@pytest.mark.parametrize("storage", ["f32", "packed", "tiered"])
-def test_search_program_carries_stage_scopes(search_hlo, storage):
+@pytest.mark.parametrize("storage,metric", STORE_METRIC)
+def test_search_program_carries_stage_scopes(search_hlo, storage, metric):
     """The compiled program's metadata names each hop stage, and its FEE
     kernel keeps the instruction name the trace reduction matches."""
     spec = importlib.util.spec_from_file_location("_bench_trace_reduce",
@@ -176,7 +182,7 @@ def test_search_program_carries_stage_scopes(search_hlo, storage):
     trace_reduce = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = trace_reduce      # its dataclass looks it up
     spec.loader.exec_module(trace_reduce)
-    hlo = search_hlo(storage)
+    hlo = search_hlo(storage, metric)
     for scope in SEARCH_SCOPES:
         assert _scoped(hlo, scope), scope
     assert any(trace_reduce.FEE_KERNEL.search(line.strip())
@@ -197,7 +203,7 @@ def test_descent_level_carries_its_scope(one_chip):
     assert _scoped(compiled.as_text(), "descent.level")
 
 
-def _lower_search(one_chip, storage):
+def _lower_search(one_chip, storage, metric):
     n, d, m = 4099, 128, 16
     cfg, tiers = _dfloat(d)
     if storage == "f32":
@@ -216,5 +222,5 @@ def _lower_search(one_chip, storage):
         _sds(one_chip, (QUERIES, d), jnp.float32),
         _sds(one_chip, (QUERIES,), jnp.int32),
         cfg=search_mod.SearchConfig(ef=64, k=10, seg=SEG, use_fee=True,
-                                    storage=storage),
+                                    storage=storage, metric=metric),
         trace=False, dfl_cfg=dcfg)
