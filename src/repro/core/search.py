@@ -17,6 +17,13 @@ prevents re-evaluation — including duplicates *across* the frontier batch's
 neighbor lists.  Early-exited candidates are visited but not inserted — this
 is exactly the recall/compute trade the paper's beta corrects.
 
+The hop body indexes no small per-query array element by element: under
+``vmap`` such an index becomes a gather or scatter of single elements,
+which the TPU runs one index at a time.  Beam, frontier and compaction
+picks are one-hot selects (:func:`onehot_take`), and the visited and
+tombstone bitmaps are read and written as 128-word rows
+(:func:`bitmap_lookup`, :func:`bitmap_mark`).
+
 ``expand=1`` reproduces the classic one-node-per-hop HNSW loop; larger values
 amortize gather/sort/host cost over ~``expand``x fewer hops at equal recall.
 
@@ -156,20 +163,84 @@ def local_topk_reduce(cand_ids, cand_d, r: int):
     return cand_ids[order], -neg_d
 
 
+def _one_hot(idx, n: int):
+    """(len(idx), n) bool: row i is set at column ``idx[i]``."""
+    return idx[:, None] == jnp.arange(n, dtype=idx.dtype)
+
+
+def onehot_take(x, idx):
+    """``x[idx]`` for a short 1-D int or bool ``x``, as a one-hot reduce.
+
+    Exact (one term per row survives).  Under ``vmap`` a plain ``x[idx]``
+    becomes a gather of single elements, which the TPU runs one index at a
+    time; the (len(idx), len(x)) compare and reduce runs as vector work.
+    """
+    hit = _one_hot(idx, x.shape[0])
+    if x.dtype == jnp.bool_:
+        return (hit & x).any(axis=1)
+    return jnp.where(hit, x, jnp.zeros((), x.dtype)).sum(axis=1,
+                                                         dtype=x.dtype)
+
+
+# uint32 words per bitmap row: one 128-lane vector row
+BITMAP_LANES = 128
+
+
+def bitmap_rows(n_bits: int) -> int:
+    """Rows of the (rows, BITMAP_LANES) uint32 bitmap that holds n_bits."""
+    return -(-n_bits // (32 * BITMAP_LANES))
+
+
+def bitmap_from_words(words):
+    """A flat (ceil(n/32),) uint32 bitmap laid out as bitmap rows."""
+    r = bitmap_rows(32 * words.shape[0])
+    return jnp.pad(words, (0, r * BITMAP_LANES - words.shape[0])).reshape(
+        r, BITMAP_LANES)
+
+
+def _bit_address(ids):
+    """(row, lane one-hot (n, BITMAP_LANES), bit) of each id >= 0."""
+    w = ids >> 5
+    return (w // BITMAP_LANES, _one_hot(w % BITMAP_LANES, BITMAP_LANES),
+            jnp.uint32(1) << (ids & 31).astype(jnp.uint32))
+
+
+def bitmap_lookup(bitmap, ids):
+    """True where bit ``ids`` (>= 0) of a bitmap-rows array is set.
+
+    Gathers whole 128-word rows and picks each id's word by its lane
+    one-hot, so the cost follows len(ids), not the bitmap's size."""
+    row, lane, bit = _bit_address(ids)
+    word = jnp.where(lane, bitmap[row], jnp.uint32(0)).sum(axis=1,
+                                                           dtype=jnp.uint32)
+    return (word & bit) != 0
+
+
+def bitmap_mark(bitmap, ids, mask):
+    """Set bit ``ids`` (>= 0) where ``mask``, by a row scatter-add.
+
+    The marked ids must be distinct and not yet set: each then adds one
+    distinct bit, which is the same as OR-ing it."""
+    row, lane, bit = _bit_address(ids)
+    upd = jnp.where(lane & mask[:, None], bit[:, None], jnp.uint32(0))
+    return bitmap.at[row].add(upd)
+
+
 def pop_frontier(beam_ids, beam_d, expanded, e: int):
     """Pop the ``e`` nearest unexpanded beam entries (the hop's frontier).
 
     Returns (nodes (e,), sel (e,), expanded'): ``nodes`` is -1 where fewer
     than ``e`` entries are active; inactive picks are already expanded or
     empty (d >= BIG), so blanket-setting ``expanded`` on them is a no-op.
+    The picks are read and flagged through their one-hot over the beam.
     Shared by the local and sharded hop bodies.
     """
     active = (~expanded) & (beam_d < BIG)
     done = ~active.any()
     _, idxs = jax.lax.top_k(-jnp.where(active, beam_d, BIG), e)
-    sel = active[idxs] & ~done
-    nodes = jnp.where(sel, beam_ids[idxs], -1)
-    return nodes, sel, expanded.at[idxs].set(True)
+    sel = onehot_take(active, idxs) & ~done
+    nodes = jnp.where(sel, onehot_take(beam_ids, idxs), -1)
+    return nodes, sel, expanded | _one_hot(idxs, expanded.shape[0]).any(0)
 
 
 def merge_beam(beam_ids, beam_d, expanded, cand_ids, cand_d):
@@ -177,15 +248,16 @@ def merge_beam(beam_ids, beam_d, expanded, cand_ids, cand_d):
 
     ``lax.top_k`` on equal keys prefers lower indices, so beam entries win
     ties against candidates (matching the stable-argsort semantics of the
-    classic loop).  Shared by the local and sharded hop bodies.
+    classic loop).  The new beam's ids and flags are read through the
+    one-hot of the picks.  Shared by the local and sharded hop bodies.
     """
     ef = beam_ids.shape[0]
     all_ids = jnp.concatenate([beam_ids, cand_ids])
     all_d = jnp.concatenate([beam_d, cand_d])
     all_exp = jnp.concatenate([expanded, jnp.zeros(cand_d.shape[0], bool)])
     neg_d, order = jax.lax.top_k(-all_d, ef)
-    beam_ids, beam_d = all_ids[order], -neg_d
-    return beam_ids, beam_d, all_exp[order] | (beam_d >= BIG)
+    beam_ids, beam_d = onehot_take(all_ids, order), -neg_d
+    return beam_ids, beam_d, onehot_take(all_exp, order) | (beam_d >= BIG)
 
 
 def _score(q, tgt, threshold, fee: FeeParams | None, cfg: SearchConfig,
@@ -237,13 +309,6 @@ def _score(q, tgt, threshold, fee: FeeParams | None, cfg: SearchConfig,
     return score, rejected, segs_used
 
 
-def tombstone_lookup(tombstone, ids):
-    """Dead-bit gather: True where ``ids`` (clamped to >= 0) is tombstoned."""
-    safe = jnp.maximum(ids, 0)
-    bit = jnp.uint32(1) << (safe & 31).astype(jnp.uint32)
-    return (tombstone[safe >> 5] & bit) != 0
-
-
 def exclude_dead(beam_ids, beam_d, tombstone):
     """Final re-rank of the beam with tombstoned entries pushed out.
 
@@ -253,10 +318,12 @@ def exclude_dead(beam_ids, beam_d, tombstone):
     dead lanes get dist BIG *and* id -1 (the underfull-beam padding), so even
     a beam with fewer than k live entries never surfaces a tombstoned id.
     """
-    dead = tombstone_lookup(tombstone, beam_ids) & (beam_ids >= 0)
+    dead = (bitmap_lookup(tombstone, jnp.maximum(beam_ids, 0))
+            & (beam_ids >= 0))
     neg_d, order = jax.lax.top_k(-jnp.where(dead, BIG, beam_d),
                                  beam_ids.shape[0])
-    return jnp.where(dead[order], -1, beam_ids[order]), -neg_d
+    return (jnp.where(onehot_take(dead, order), -1,
+                      onehot_take(beam_ids, order)), -neg_d)
 
 
 def _hop_body(state, vectors, adj, q, fee: FeeParams | None, cfg: SearchConfig,
@@ -271,10 +338,8 @@ def _hop_body(state, vectors, adj, q, fee: FeeParams | None, cfg: SearchConfig,
         nbrs = adj[jnp.maximum(nodes, 0)].reshape(e * m)       # (E*M,)
         valid = (nbrs >= 0) & jnp.repeat(sel, m)
         safe = jnp.maximum(nbrs, 0)
-        w = safe >> 5
-        bit = (jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
-        seen = (visited[w] & bit) != 0
-        fresh = valid & ~seen & first_occurrence_mask(safe, valid)
+        fresh = (valid & ~bitmap_lookup(visited, safe)
+                 & first_occurrence_mask(safe, valid))
 
         # ---- fresh-first frontier compaction (expand > 1): after the
         # visited/dedup filter, typically well under half the E*M slots
@@ -288,20 +353,19 @@ def _hop_body(state, vectors, adj, q, fee: FeeParams | None, cfg: SearchConfig,
         if e > 1:
             l = compact_width(m, e, cfg.compact)
             _, keep = jax.lax.top_k(fresh.astype(jnp.float32), l)
-            nbrs, safe, fresh = nbrs[keep], safe[keep], fresh[keep]
-            w = safe >> 5
-            bit = jnp.uint32(1) << (safe & 31).astype(jnp.uint32)
+            nbrs, fresh = onehot_take(nbrs, keep), onehot_take(fresh, keep)
+            safe = jnp.maximum(nbrs, 0)
             src = keep // m                                # parent pop slot
         else:
             src = jnp.arange(e * m, dtype=jnp.int32) // m
-        visited = visited.at[w].add(jnp.where(fresh, bit, jnp.uint32(0)))
+        visited = bitmap_mark(visited, safe, fresh)
 
         # tombstoned lanes stay in ``fresh`` (visited-marked, never
         # re-checked) but are folded into the FEE exit mask: zero segments
         # streamed, never inserted into the beam, and invisible to the trace
         # (``live``).
         alive = (None if tombstone is None
-                 else ~tombstone_lookup(tombstone, safe))
+                 else ~bitmap_lookup(tombstone, safe))
         live = fresh if alive is None else fresh & alive
 
     threshold = beam_d[-1]
@@ -339,7 +403,7 @@ def _hop_body(state, vectors, adj, q, fee: FeeParams | None, cfg: SearchConfig,
     return (beam_ids, beam_d, expanded, visited), trace
 
 
-def _init_state(q, entry, vectors, cfg: SearchConfig, n_words,
+def _init_state(q, entry, vectors, cfg: SearchConfig, n_rows,
                 dfl_cfg: dfl.DfloatConfig | None = None):
     ef = cfg.ef
     if cfg.storage == "tiered":
@@ -354,8 +418,9 @@ def _init_state(q, entry, vectors, cfg: SearchConfig, n_words,
     beam_ids = jnp.full((ef,), -1, jnp.int32).at[0].set(entry)
     beam_d = jnp.full((ef,), BIG, jnp.float32).at[0].set(d0)
     expanded = jnp.ones((ef,), bool).at[0].set(False)
-    visited = jnp.zeros((n_words,), jnp.uint32)
-    visited = visited.at[entry >> 5].set(jnp.uint32(1) << (entry & 31).astype(jnp.uint32))
+    visited = bitmap_mark(jnp.zeros((bitmap_rows(n_rows), BITMAP_LANES),
+                                    jnp.uint32), entry[None],
+                          jnp.ones((1,), bool))
     return beam_ids, beam_d, expanded, visited
 
 
@@ -370,13 +435,15 @@ def _search_batch(vectors, adj, fee, tombstone, queries, entries, *,
     index — or re-creating a searcher — never re-traces or re-lowers.
     ``vectors`` is the packed (N, W) uint32 bitstream when
     ``cfg.storage == "packed"`` (``dfl_cfg`` supplies the static layout).
-    ``tombstone`` is the optional dead-row bitmap ((ceil(N/32),) uint32, or
-    None for an immutable index — None flattens to nothing, so the static
-    jit key distinguishes the two shapes of program).
+    ``tombstone`` is the optional dead-row bitmap ((ceil(N/32),) uint32,
+    laid out as bitmap rows here; or None for an immutable index — None
+    flattens to nothing, so the static jit key distinguishes the two shapes
+    of program).
     """
     tiered = cfg.storage == "tiered"
     n_rows = (vectors[0] if tiered else vectors).shape[0]
-    n_words = -(-n_rows // 32)
+    if tombstone is not None:
+        tombstone = bitmap_from_words(tombstone)
 
     # hop counters carried through the early-terminating fast path for every
     # storage (cheap: one int32 add per hop) — serving reports the live FEE
@@ -386,7 +453,7 @@ def _search_batch(vectors, adj, fee, tombstone, queries, entries, *,
 
     def search_one(q, entry):
         with jax.named_scope("search.init"):
-            state = _init_state(q, entry, vectors, cfg, n_words, dfl_cfg)
+            state = _init_state(q, entry, vectors, cfg, n_rows, dfl_cfg)
         counters = None
         if trace:
             def step(s, _):

@@ -78,3 +78,129 @@ def test_untraced_search_uses_early_termination(unit_db, unit_index):
                                SearchParams(ef=32, k=10, trace=True))
     assert fast.trace is None and traced.trace is not None
     np.testing.assert_array_equal(fast.ids, traced.ids)
+
+
+def _np_bitmap(n, ids):
+    words = np.zeros(-(-n // 32), np.uint32)
+    np.bitwise_or.at(words, ids >> 5,
+                     np.uint32(1) << (ids & 31).astype(np.uint32))
+    return words
+
+
+# (rows, ids marked per query): word and row edges, the last row of an n
+# that is a multiple of neither 32 nor 4,096 (the bits of one bitmap row),
+# several distinct bits of one word, and several queries under vmap
+_BITMAP_CASES = {
+    "id0": (4099, [[0]]),
+    "word_edge": (4099, [[31, 32]]),
+    "last": (4099, [[4098]]),
+    "row_edge": (4099, [[4095, 4096]]),
+    "one_word": (4099, [[64, 65, 70, 95]]),
+    "exact_row": (4096, [[0, 4095]]),
+    "spread": (9001, [list(range(3, 9001, 37))]),
+    "vmap": (4099, [[0, 31, 32], [4098, 64, 95], [7, 8, 4000]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BITMAP_CASES))
+def test_bitmap_rows_match_numpy(case):
+    """Row-granular bitmap lookup and mark against a flat numpy bitmap: a
+    masked-off lane marks nothing, and the rows read back as the flat
+    words (``bitmap_from_words``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import search as s
+
+    n, marks = _BITMAP_CASES[case]
+    ids = np.asarray(marks, np.int32)                       # (Q, k)
+    # one extra lane per query, masked off: it must stay unmarked
+    spare = np.asarray([(n - 2 - i) for i in range(len(ids))], np.int32)
+    lanes = np.concatenate([ids, spare[:, None]], axis=1)
+    mask = np.ones(lanes.shape, bool)
+    mask[:, -1] = False
+    empty = jnp.zeros((s.bitmap_rows(n), s.BITMAP_LANES), jnp.uint32)
+    rows = jax.vmap(lambda i, m: s.bitmap_mark(empty, i, m))(lanes, mask)
+    every = jnp.arange(n, dtype=jnp.int32)
+    seen = np.asarray(jax.vmap(lambda r: s.bitmap_lookup(r, every))(rows))
+    for qi, want in enumerate(ids):
+        words = _np_bitmap(n, want[~np.isin(want, spare[qi])])
+        flat = np.asarray(rows[qi]).reshape(-1)
+        np.testing.assert_array_equal(flat[: len(words)], words)
+        assert not flat[len(words):].any()
+        np.testing.assert_array_equal(
+            np.asarray(s.bitmap_from_words(jnp.asarray(words))), rows[qi])
+        bits = (words[every >> 5] >> (np.asarray(every) & 31)) & 1
+        np.testing.assert_array_equal(seen[qi], bits.astype(bool))
+
+
+def _take_reference(name, rng):
+    """(values, picks) of one select the hop body makes, with the picks
+    from ``lax.top_k`` over keys full of ties."""
+    import jax
+
+    if name == "merge":             # ef + L candidates, tied distances
+        d = rng.integers(0, 6, 104).astype(np.float32)
+        d[rng.random(104) < 0.3] = 3.0e38
+        _, order = jax.lax.top_k(-d, 64)
+        return rng.integers(-1, 40000, 104).astype(np.int32), order
+    if name == "compaction":        # the fresh mask, 40 of 80 lanes kept
+        fresh = rng.random(80) < 0.3
+        _, keep = jax.lax.top_k(fresh.astype(np.float32), 40)
+        return rng.integers(-1, 40000, 80).astype(np.int32), keep
+    assert name == "pop"            # 4 of an ef beam, most already popped
+    d = np.where(rng.random(64) < 0.8, np.float32(3.0e38),
+                 rng.integers(0, 3, 64).astype(np.float32))
+    _, idxs = jax.lax.top_k(-d, 4)
+    return rng.integers(-1, 40000, 64).astype(np.int32), idxs
+
+
+@pytest.mark.parametrize("name", ["merge", "compaction", "pop"])
+def test_onehot_take_matches_take(name):
+    """The one-hot selects of the beam merge, the compaction and the
+    frontier pop equal ``jnp.take`` for int ids and bool flags, singly and
+    under vmap; ``merge_beam`` and ``pop_frontier`` equal their
+    index-by-index forms."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import search as s
+
+    rng = np.random.default_rng(len(name))
+    cases = [_take_reference(name, rng) for _ in range(8)]
+    for x, idx in cases:
+        flags = jnp.asarray(x % 3 == 0)
+        np.testing.assert_array_equal(s.onehot_take(jnp.asarray(x), idx),
+                                      jnp.take(x, idx))
+        np.testing.assert_array_equal(s.onehot_take(flags, idx),
+                                      jnp.take(flags, idx))
+    xs = jnp.stack([jnp.asarray(x) for x, _ in cases])
+    idxs = jnp.stack([i for _, i in cases])
+    np.testing.assert_array_equal(jax.vmap(s.onehot_take)(xs, idxs),
+                                  jnp.take_along_axis(xs, idxs, axis=1))
+
+    ids = rng.integers(0, 40000, (8, 64)).astype(np.int32)
+    d = np.sort(rng.integers(0, 9, (8, 64)).astype(np.float32), axis=1)
+    d[:, 50:] = 3.0e38
+    exp = rng.random((8, 64)) < 0.5
+    cand_ids = rng.integers(0, 40000, (8, 40)).astype(np.int32)
+    cand_d = rng.integers(0, 9, (8, 40)).astype(np.float32)
+
+    def merge_ref(bi, bd, ex, ci, cd):
+        all_ids = jnp.concatenate([bi, ci])
+        all_exp = jnp.concatenate([ex, jnp.zeros(cd.shape[0], bool)])
+        neg, order = jax.lax.top_k(-jnp.concatenate([bd, cd]), bi.shape[0])
+        return all_ids[order], -neg, all_exp[order] | (-neg >= s.BIG)
+
+    def pop_ref(bi, bd, ex):
+        active = (~ex) & (bd < s.BIG)
+        _, i = jax.lax.top_k(-jnp.where(active, bd, s.BIG), 4)
+        sel = active[i] & active.any()
+        return jnp.where(sel, bi[i], -1), sel, ex.at[i].set(True)
+
+    for got, want in ((jax.vmap(s.merge_beam)(ids, d, exp, cand_ids, cand_d),
+                       jax.vmap(merge_ref)(ids, d, exp, cand_ids, cand_d)),
+                      (jax.vmap(lambda *a: s.pop_frontier(*a, 4))(ids, d, exp),
+                       jax.vmap(pop_ref)(ids, d, exp))):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)

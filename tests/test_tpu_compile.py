@@ -43,6 +43,7 @@ LAYOUTS = {128: [(21, 6, 48), (14, 5, 80)],
 # jax.named_scope stages of one hop and of the search's set-up
 SEARCH_SCOPES = ("search.init", "hop.frontier", "hop.gather", "hop.score",
                  "hop.merge")
+HOP_SCOPES = SEARCH_SCOPES[1:]
 TRACE_REDUCE = Path(__file__).resolve().parents[1] / "bench" / "trace_reduce.py"
 STORAGES = ("f32", "packed", "tiered")
 # every storage under each metric; the l2 cases keep their plain ids
@@ -164,6 +165,58 @@ def _scoped(hlo: str, scope: str) -> bool:
     (``jit(f)/vmap(hop.score)/...`` under vmap)."""
     pattern = r'op_name="[^"]*[/(]' + re.escape(scope) + r'[/)]'
     return re.search(pattern, hlo) is not None
+
+
+def _dims(text: str, key: str) -> list[int]:
+    m = re.search(key + r"=\{([\d,]*)\}", text)
+    return [int(v) for v in m.group(1).split(",") if v] if m else []
+
+
+def _element_ops(hlo: str) -> list[tuple[str, list[str]]]:
+    """Every gather whose slice sizes are all 1, and every scatter whose
+    update window is all 1 (the TPU runs these one index at a time), with
+    the ``op_name``s that speak for it: its own and, inside a fused
+    computation, those of that computation and of the fusions calling it."""
+    shapes, names_in, callers, found, comp = {}, {}, {}, [], None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+            continue
+        inst = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", line)
+        if not inst:
+            continue
+        shapes[inst.group(1)] = [int(v) for v in inst.group(2).split(",") if v]
+        names = re.findall(r'op_name="([^"]*)"', line)
+        names_in.setdefault(comp, []).extend(names)
+        for callee in re.findall(r"calls=%([\w.\-]+)", line):
+            callers.setdefault(callee, []).extend(names)
+        if " gather(" in line:
+            window = _dims(line, "slice_sizes")
+        elif " scatter(" in line:
+            operands = re.findall(r"%([\w.\-]+)",
+                                  line.split(" scatter(", 1)[1].split(")")[0])
+            update = shapes[operands[-1]]
+            window = [update[d] for d in _dims(line, "update_window_dims")]
+        else:
+            continue
+        if all(v == 1 for v in window):
+            found.append((inst.group(1), comp, names))
+    return [(op, names + (names_in[comp] + callers[comp]
+                          if comp in callers else []))
+            for op, comp, names in found]
+
+
+@pytest.mark.parametrize("storage,metric", STORE_METRIC)
+def test_hop_body_has_no_element_gathers(search_hlo, storage, metric):
+    """No gather or scatter of single elements in the hop's stages: beam
+    merge, compaction, frontier pop and the visited bitmap run as vector
+    work (one-hot selects, 128-word bitmap rows); row gathers pass."""
+    hop_ops = [(op, names) for op, names in _element_ops(
+                   search_hlo(storage, metric))
+               if any(_scoped(f'op_name="{n}"', scope) for n in names
+                      for scope in HOP_SCOPES)]
+    assert not hop_ops, hop_ops
 
 
 @pytest.mark.parametrize("storage,metric", STORE_METRIC)
